@@ -563,7 +563,7 @@ def gateway_sharding() -> list[str]:
     for mesh in (1, 2, 4):
         env = dict(os.environ)
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        env.pop("JAX_PLATFORMS", None)
+        env["JAX_PLATFORMS"] = "cpu"  # emulated host devices, never the chip
         kv, detail = _marker_subprocess(
             [sys.executable, "-c", _SHARDING_SCRIPT, str(mesh)],
             "SHARDING ", env,

@@ -49,7 +49,7 @@ _JIT_WRAPPERS = {
     "jax.jit", "jit", "jax.pjit", "pjit",
 }
 _TRACE_WRAPPERS = _JIT_WRAPPERS | {
-    "shard_map", "jax.experimental.shard_map.shard_map",
+    "shard_map", "jax.shard_map",
     "pallas_call", "pl.pallas_call", "jax.experimental.pallas.pallas_call",
     "jax.vmap", "vmap", "jax.grad", "grad", "jax.value_and_grad",
     "jax.lax.scan", "lax.scan", "jax.lax.fori_loop", "lax.fori_loop",

@@ -26,7 +26,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.config.core import ModelConfig
 from repro.core.balancing import stage_assignment_for
@@ -147,12 +146,6 @@ def pipelined_forward(
     counts: (S,) layers per stage.  Stages beyond the model depth idle and
     pass activations through — utilisation is reported by the balancing
     module, mirroring the paper's Table-1 discussion.
-
-    Compilation caveat: do NOT trace :func:`build_stage_params` and this
-    function into one ``jax.jit`` program when the batch mesh axis is >1 —
-    on jax 0.4.37 the SPMD partitioner produces wrong stage weights for
-    that combined program.  Compile them separately (the engine's
-    "pipelined" schedule in engine/schedules.py does this).
     """
     n_stages = counts.shape[0]
     t_len, b, f = xs.shape
@@ -209,8 +202,8 @@ def pipelined_forward(
     # out: (S, K, B, in_max) — stage-major stack of every stage's stream
     out_specs = P(stage_axis, None, batch_axes, None)
 
-    fn = shard_map(
-        stage_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    fn = jax.shard_map(
+        stage_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
     all_streams = fn(stage_params, counts, xs_ext)
     # Stages with zero layers pass activations through, so the final stage's

@@ -1,5 +1,10 @@
 from repro.data.lm import LMDataConfig, LMIterator, host_slice, make_lm_batch
-from repro.data.timeseries import TimeseriesConfig, TimeseriesIterator, make_batch
+from repro.data.timeseries import (
+    TimeseriesConfig,
+    TimeseriesIterator,
+    make_batch,
+    make_batch_np,
+)
 
 __all__ = [
     "LMDataConfig",
@@ -8,5 +13,6 @@ __all__ = [
     "TimeseriesIterator",
     "host_slice",
     "make_batch",
+    "make_batch_np",
     "make_lm_batch",
 ]

@@ -55,13 +55,20 @@ def _inject_anomalies(rng: np.random.Generator, x: np.ndarray, mask: np.ndarray)
     return out
 
 
-def make_batch(cfg: TimeseriesConfig, index: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Deterministic batch #index -> (series (B,T,F), labels (B,) 1=anomaly)."""
+def make_batch_np(cfg: TimeseriesConfig, index: int) -> tuple[np.ndarray, np.ndarray]:
+    """:func:`make_batch` as host numpy arrays, touching no JAX backend (a
+    load generator beside processes that hold the chips)."""
     rng = np.random.default_rng(np.random.SeedSequence([cfg.seed, index]))
     x = _benign_batch(rng, cfg.batch, cfg.seq_len, cfg.features)
     labels = (rng.uniform(size=cfg.batch) < cfg.anomaly_rate).astype(np.int32)
     if labels.any():
         x = _inject_anomalies(rng, x, labels)
+    return x, labels
+
+
+def make_batch(cfg: TimeseriesConfig, index: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Deterministic batch #index -> (series (B,T,F), labels (B,) 1=anomaly)."""
+    x, labels = make_batch_np(cfg, index)
     return jnp.asarray(x), jnp.asarray(labels)
 
 

@@ -139,8 +139,8 @@ class Engine:
 
         # Whole-request programs (transpose + forward + reduction fused),
         # jitted as one unit unless the schedule manages its own compilation
-        # (prejitted, e.g. pipelined — its shard_map programs must not be
-        # inlined into an enclosing jit; see schedules.py).
+        # (prejitted, e.g. pipelined — its program runs on its own stage
+        # mesh; see schedules.py).
         def _reconstruct(params, series):
             xs = jnp.swapaxes(series, 0, 1)
             return jnp.swapaxes(fwd(params, xs), 0, 1)

@@ -47,7 +47,7 @@ import functools
 from dataclasses import dataclass
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 @functools.lru_cache(maxsize=None)
@@ -63,7 +63,10 @@ def _mesh_for(data_shards: int, data_axis: str) -> Mesh:
             f"XLA_FLAGS=--xla_force_host_platform_device_count={data_shards} "
             f"to emulate, or shrink the placement"
         )
-    return jax.make_mesh((data_shards,), (data_axis,),
+    # Auto axes: the pool's slot updates (``.at[slot].set``) index arrays
+    # sharded on this mesh, which Explicit axes (make_mesh's default)
+    # refuse with ShardingTypeError
+    return jax.make_mesh((data_shards,), (data_axis,), (AxisType.Auto,),
                          devices=devices[:data_shards])
 
 

@@ -15,7 +15,7 @@ consumer (serving, benchmarks, examples) selects it by name:
   to the wavefront schedule (same dataflow semantics, no stage axis).
 * ``"fused"``      — the Pallas fused-cell kernel (kernels/lstm_cell.py:
   MVM_X + MVM_H + gates + element-wise as one MXU kernel) scanned over the
-  (layer, time) grid; interpret-mode fallback off-TPU.
+  (layer, time) grid; compiled on TPU, interpreted on CPU.
 
 Third-party backends register with :func:`register_schedule`; see README
 §Execution engine for the contract.
@@ -28,6 +28,7 @@ from typing import Callable, NamedTuple, Optional, TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.config.core import ModelConfig
 from repro.core.lstm import lstm_ae_sequential
@@ -48,7 +49,7 @@ class Schedule(NamedTuple):
     latency_kind: str    # "dataflow" | "sequential" (core.latency Eq-1 mode)
     forward: ForwardFn
     # True when the factory already manages compilation internally (the
-    # Engine must NOT wrap forward in an outer jax.jit; see _pipelined)
+    # Engine must NOT wrap forward in its own programs; see _pipelined)
     prejitted: bool = False
 
     @property
@@ -200,12 +201,20 @@ def _wavefront(cfg: ModelConfig, ecfg: "EngineConfig") -> Schedule:
     return Schedule("wavefront", "wavefront", "dataflow", forward)
 
 
-def _divisor_block(n: int, cap: int = 128) -> int:
-    """Largest block size <= cap that divides n (Pallas grid constraint)."""
-    d = min(n, cap)
-    while n % d:
-        d -= 1
-    return d
+def _divisor_block(n: int, cap: int = 128, align: int = 8) -> int:
+    """Block size for a Pallas grid dimension of extent ``n``.
+
+    Mosaic accepts a block only when it spans the whole dimension or is a
+    multiple of the tile (8 sublanes for rows, 128 lanes for the minor
+    dimension), so: the whole dimension when it fits under ``cap``, else
+    the largest multiple of ``align`` <= cap that divides ``n``, else the
+    whole dimension."""
+    if n <= cap:
+        return n
+    for d in range(cap - cap % align, 0, -align):
+        if n % d == 0:
+            return d
+    return n
 
 
 @register_schedule("fused", config_fields=("pwl",))
@@ -213,11 +222,12 @@ def _fused(cfg: ModelConfig, ecfg: "EngineConfig") -> Schedule:
     """Pallas fused-cell schedule (ROADMAP follow-up): scans the fused
     MVM_X+MVM_H+gates kernel of ``kernels/lstm_cell.py`` over the
     (layer, time) grid layer-by-layer — the paper's single-module datapath
-    as one MXU kernel per (layer, timestep).  Falls back to interpret mode
-    off-TPU so CPU CI exercises the same kernel code."""
+    as one MXU kernel per (layer, timestep).  Compiled on TPU; interpreted
+    on CPU so CPU CI exercises the same kernel code."""
     from repro.kernels.lstm_cell import lstm_cell_pallas, pack_weights
+    from repro.kernels.ops import pallas_interpret
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
 
     def forward(params, xs):
         ys = xs
@@ -226,7 +236,7 @@ def _fused(cfg: ModelConfig, ecfg: "EngineConfig") -> Schedule:
             bsz = ys.shape[1]
             hidden = wh.shape[1]
             block_b = _divisor_block(bsz)
-            block_h = _divisor_block(hidden)
+            block_h = _divisor_block(hidden, align=128)
             h0 = jnp.zeros((bsz, hidden), ys.dtype)
             c0 = jnp.zeros((bsz, hidden), jnp.float32)
 
@@ -276,42 +286,21 @@ def _pipelined(cfg: ModelConfig, ecfg: "EngineConfig") -> Schedule:
         )
     mesh = jax.make_mesh(
         (data_par, n_stages), (ecfg.data_axis, ecfg.stage_axis),
-        devices=devices[:need],
+        (AxisType.Auto, AxisType.Auto), devices=devices[:need],
     )
 
     # Stage grouping (balanced DP over per-timestep FLOPs) is encapsulated
-    # here — callers never hand-build stage params or meshes.
-    #
-    # The two halves are compiled as SEPARATE programs on purpose: tracing
-    # build_stage_params and the shard_map into ONE jit miscompiles on
-    # jax 0.4.37 when the data mesh axis is >1 (the SPMD partitioner
-    # produces wrong wx/wh stage weights; verified by value comparison).
-    # Splitting the programs sidesteps the bug, so this Schedule is
-    # ``prejitted`` and the Engine must not re-wrap it.
-    def _build(params):
+    # here — callers never hand-build stage params or meshes.  The program
+    # runs on its own (data, stage) mesh, which the Engine's data-placement
+    # shardings do not describe, so this Schedule is ``prejitted`` and the
+    # Engine must not wrap it in its row-sharded programs.
+    def forward(params, xs):
         stage_params, counts, _ = build_stage_params(params, cfg, n_stages)
-        return stage_params, counts
-
-    def _run(stage_params, counts, xs):
         return pipelined_forward(
             stage_params, counts, xs, mesh=mesh, cfg=cfg,
             stage_axis=ecfg.stage_axis, batch_axes=(ecfg.data_axis,),
             pwl=ecfg.pwl,
         )
 
-    build = jax.jit(_build) if ecfg.jit else _build
-    run = jax.jit(_run) if ecfg.jit else _run
-
-    def forward(params, xs):
-        if data_par > 1 and isinstance(xs, jax.core.Tracer):
-            raise RuntimeError(
-                "pipelined schedule with data_parallel>1 must not be traced "
-                "into an enclosing jax.jit: inlining re-merges the two "
-                "programs and hits the jax-0.4.37 shard_map miscompile "
-                "(see core/temporal.py). Call it un-jitted — Engine/"
-                "AnomalyService do this automatically."
-            )
-        stage_params, counts = build(params)
-        return run(stage_params, counts, xs)
-
-    return Schedule("pipelined", "pipelined", "dataflow", forward, prejitted=True)
+    return Schedule("pipelined", "pipelined", "dataflow",
+                    jax.jit(forward) if ecfg.jit else forward, prejitted=True)

@@ -39,6 +39,7 @@ from __future__ import annotations
 import time
 from typing import Callable, Hashable, Mapping, Optional, Sequence, Union
 
+import jax
 import numpy as np
 
 from repro.engine.base import Engine
@@ -241,6 +242,12 @@ class AnomalyGateway:
             features=self.batcher.features,
             threshold=self.threshold,
         )
+        # the devices this process serves on: a front's per-worker view
+        # shows each worker confined to its own chip (count 1)
+        devices = jax.devices()
+        out["device"] = {"platform": devices[0].platform,
+                         "kind": devices[0].device_kind,
+                         "count": len(devices)}
         # compile visibility: per-program/per-shape compile counts + wall
         # time from the engine, resolve-cache hit/miss from the registry —
         # recompile storms on the bucket ladder show up here

@@ -223,12 +223,13 @@ class GatewayServer:
         """Graceful shutdown: stop accepting connections, flush the
         micro-batch queue (every pending ticket completes — scored or
         failed — and its response is written), then evict remaining
-        sessions and close the connections."""
+        sessions and close the connections.  ``wait_closed`` comes last:
+        it waits for every client connection to close (Python 3.12+), so
+        awaiting it before the writers are closed would deadlock."""
         self._draining = True
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+        server, self._server = self._server, None
+        if server is not None:
+            server.close()  # stop accepting; open connections stay up
         if self._pump_task is not None:
             self._pump_task.cancel()
             await asyncio.gather(self._pump_task, return_exceptions=True)
@@ -255,6 +256,12 @@ class GatewayServer:
                              exc_info=True)
         if self._handlers:  # handlers evict their sessions on the way out
             await asyncio.wait(self._handlers, timeout=timeout)
+        if server is not None:
+            try:
+                await asyncio.wait_for(server.wait_closed(), timeout)
+            except TimeoutError:
+                logger.warning("drain: connections still open after %.1fs",
+                               timeout)
         self.gateway.events.emit(
             "drain", active_streams=self.gateway.pool.active,
             queue_depth=self.gateway.batcher.queue_depth,

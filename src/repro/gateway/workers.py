@@ -60,6 +60,11 @@ map for overlap before spawning anything, and each worker registers its
 claim in the store's :class:`~repro.gateway.claims.DeviceClaimRegistry`
 at boot — two workers claiming one device is a boot error naming both.
 
+On a TPU host each worker is confined to one chip (worker ``i`` gets the
+``i``-th chip of :func:`~repro.gateway.claims.host_tpu_chips`) through
+libtpu's per-process variables, and a front with more workers than chips
+refuses to start.  The supervisor itself never initialises a JAX backend.
+
 Workers are spawned (not forked): JAX state must never be forked, and
 ``env`` overrides (e.g. ``XLA_FLAGS`` for a per-worker device mesh) are
 applied to the environment the child boots with, before any JAX backend
@@ -205,7 +210,9 @@ def _worker_main(index: int, conn, host: str, port: int,
         signal.signal(_sig, lambda *_: boot_stop.set())
 
     from repro.gateway.server import GatewayServer
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     owner = f"worker-{index}"
     obs = obs or {}
     metrics = None
@@ -346,6 +353,12 @@ def _worker_main(index: int, conn, host: str, port: int,
 # ---------------------------------------------------------------------------
 # supervisor side
 # ---------------------------------------------------------------------------
+
+
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
 
 
 class _Worker:
@@ -491,6 +504,7 @@ class WorkerFront:
         self._last_recalibrate: Optional[dict] = None
         self._last_batching: Optional[dict] = None
         self._ctx = mp.get_context("spawn")  # never fork a JAX parent
+        self._chips: list[int] = []  # TPU chip per worker index (start())
         self._workers: dict[int, _Worker] = {}
         self._reserve: Optional[socket.socket] = None
         self._rid = itertools.count()
@@ -507,6 +521,14 @@ class WorkerFront:
         server is bound; returns ``(host, port)``."""
         if self._started:
             raise RuntimeError("front already started")
+        from repro.gateway.claims import host_tpu_chips
+
+        self._chips = host_tpu_chips()
+        if self._chips and self.n_workers > len(self._chips):
+            raise RuntimeError(
+                f"{self.n_workers} workers need one TPU chip each; this "
+                f"host offers {len(self._chips)} ({self._chips})"
+            )
         self._reserve = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._reserve.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEPORT, 1)
         self._reserve.bind((self.host, self.port))
@@ -590,9 +612,10 @@ class WorkerFront:
         # env overrides (XLA_FLAGS et al.) must be in the child's boot
         # environment BEFORE any of its imports run — spawn inherits the
         # parent environment at exec time, so apply/restore around start()
-        saved = {k: os.environ.get(k) for k in self.env}
+        env = self._child_env(index)
+        saved = {k: os.environ.get(k) for k in env}
         try:
-            os.environ.update(self.env)
+            os.environ.update(env)
             proc.start()
         finally:
             for k, v in saved.items():
@@ -606,6 +629,18 @@ class WorkerFront:
             target=self._reader_loop, args=(worker,),
             name=f"front-reader-{index}", daemon=True,
         ).start()
+
+    def _child_env(self, index: int) -> dict:
+        """Boot environment overrides of worker ``index``: the front's
+        ``env`` plus, on a TPU host, the libtpu variables that give the
+        worker its own chip."""
+        env = dict(self.env)
+        if self._chips:
+            from repro.gateway.claims import tpu_worker_env
+
+            env.update(tpu_worker_env(self._chips[index], _free_port(),
+                                      _free_port()))
+        return env
 
     def _close_reserve(self) -> None:
         if self._reserve is not None:
@@ -674,20 +709,23 @@ class WorkerFront:
         while not self._shutting_down:
             with self._lock:  # scale_down() removes entries concurrently
                 workers = list(self._workers.values())
+            # a worker being scaled down is reaped by scale_down() alone:
+            # two threads polling one child race on waitpid, and the loser
+            # reads the exited child as alive with no exit code
             sentinels = {w.proc.sentinel: w for w in workers
-                         if w.proc.is_alive()}
+                         if not w.scaling_down and w.proc.is_alive()}
             if not sentinels:
                 time.sleep(0.05)
                 continue
             dead = mp.connection.wait(list(sentinels), timeout=0.25)
             for s in dead:
                 w = sentinels[s]
+                if self._shutting_down or w.scaling_down:
+                    continue  # shutdown() / scale_down() reap their workers
                 w.proc.join(1.0)
                 w.exitcode = w.proc.exitcode
-                if self._shutting_down or w.drain_summary is not None \
-                        or w.scaling_down:
-                    continue  # a drained exit is handled by shutdown()
-                    # (or by scale_down(), which owns its worker's drain)
+                if w.drain_summary is not None:
+                    continue  # a drained exit, not a crash
                 # with a snapshot store the victim's residents are not
                 # lost — any worker can resume them from its shard — so
                 # only count them against a front running without one
@@ -976,6 +1014,11 @@ class WorkerFront:
             index = 0
             while index in self._workers:
                 index += 1
+            if self._chips and index >= len(self._chips):
+                raise RuntimeError(
+                    f"cannot scale up: every TPU chip ({self._chips}) "
+                    f"already has a worker"
+                )
             self.target_workers = len(self._workers) + 1
             self.scale_ups += 1
         self._spawn(index)

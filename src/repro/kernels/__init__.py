@@ -4,7 +4,7 @@
                       — the paper's per-module datapath as one MXU pass)
 - wkv6.py             RWKV6 recurrence chunk (VMEM-resident state)
 - flash_attention.py  causal flash attention (prefill shapes)
-- ops.py              jitted public wrappers (interpret=True on CPU)
+- ops.py              jitted public wrappers (interpreted on CPU, compiled on TPU)
 - ref.py              pure-jnp oracles (the allclose targets)
 """
 from repro.kernels.ops import flash_attention_op, lstm_cell_op, wkv6_op

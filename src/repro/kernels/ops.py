@@ -1,7 +1,7 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on real
-TPU — resolved once at import from the local backend, overridable per call.
+``interpret`` defaults to :func:`pallas_interpret` of the default backend
+(True on CPU, False on TPU, an error anywhere else), overridable per call.
 The wrappers adapt framework-native layouts (e.g. core/lstm.py param dicts,
 (B,S,H,d) attention tensors) to kernel layouts.
 """
@@ -17,8 +17,21 @@ from repro.kernels.lstm_cell import lstm_cell_pallas, pack_weights
 from repro.kernels.wkv6 import wkv6_pallas
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def pallas_interpret() -> bool:
+    """Whether Pallas kernels run in interpret mode on the default backend.
+
+    Only the CPU interprets (tests and rehearsals); the TPU compiles them.
+    Any other platform is refused: emulating there would hide that the
+    kernels never reached a device that can run them."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels compile for TPU and are interpreted on CPU; "
+        f"no path for platform {platform!r}"
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "block_h", "pwl", "interpret"))
@@ -26,7 +39,7 @@ def lstm_cell_op(params, x, h, c, *, block_b: int = 128, block_h: int = 128,
                  pwl: bool = False, interpret: bool | None = None):
     """Fused LSTM cell using core/lstm.py param layout {wx, wh, b}."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = pallas_interpret()
     wx, wh, b = pack_weights(params)
     return lstm_cell_pallas(
         x, h, c, wx, wh, b, block_b=block_b, block_h=block_h, pwl=pwl,
@@ -38,7 +51,7 @@ def lstm_cell_op(params, x, h, c, *, block_b: int = 128, block_h: int = 128,
 def wkv6_op(r, k, v, w, u, s0, *, interpret: bool | None = None):
     """WKV6 recurrence: r/k/v/w (B,T,H,hd), u (H,hd), s0 (B,H,hd,hd)."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = pallas_interpret()
     return wkv6_pallas(r, k, v, w, u, s0, interpret=interpret)
 
 
@@ -51,7 +64,7 @@ def lstm_seq_op(params, xs, h0=None, c0=None, *, block_b: int = 256,
     from repro.kernels.lstm_seq import lstm_seq_pallas
 
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = pallas_interpret()
     wx, wh, b = pack_weights(params)
     bsz = xs.shape[1]
     hidden = wh.shape[1]
@@ -71,7 +84,7 @@ def flash_attention_op(q, k, v, *, causal: bool = True, block_q: int = 512,
                        block_k: int = 512, interpret: bool | None = None):
     """Flash attention over (B, S, H, d) layout (framework-native)."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = pallas_interpret()
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

@@ -8,6 +8,7 @@ import; smoke tests and benchmarks see the real single CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,7 +25,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "(launch/dryrun.py does this automatically)"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape),
+                         devices=devices[:need])
 
 
 def make_host_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
@@ -35,4 +37,5 @@ def make_host_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     devices = jax.devices()
     if len(devices) < need:
         raise RuntimeError(f"need {need} devices, have {len(devices)}")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape),
+                         devices=devices[:need])

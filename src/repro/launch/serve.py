@@ -43,6 +43,7 @@ from repro.data import TimeseriesConfig, make_batch
 from repro.engine import AnomalyService, EngineConfig, Placement, available_schedules
 from repro.models import build_model
 from repro.serving import greedy_decode_loop
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def engine_cfg_for(args) -> "object":
@@ -446,6 +447,7 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true", default=True)
     ap.add_argument("--full-config", dest="reduced", action="store_false")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if cfg.family == "lstm_ae":

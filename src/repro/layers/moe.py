@@ -26,7 +26,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config.core import ModelConfig
@@ -202,7 +201,7 @@ def apply_moe_ep(params: Params, x: jnp.ndarray, cfg: ModelConfig) -> tuple[jnp.
         return y.astype(x_loc.dtype).reshape(b_loc, s_loc, d), aux
 
     x_spec = P(batch_axes, model_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(
@@ -213,7 +212,7 @@ def apply_moe_ep(params: Params, x: jnp.ndarray, cfg: ModelConfig) -> tuple[jnp.
             x_spec,
         ),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(params["router"], params["gate"], params["up"], params["down"], x)
 
@@ -281,7 +280,7 @@ def _apply_moe_ep_replicated(params, x, cfg: ModelConfig, mesh, rules):
         return y.astype(x_loc.dtype).reshape(b_loc, s_loc, d), aux
 
     x_spec = P(batch_axes, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(
@@ -292,7 +291,7 @@ def _apply_moe_ep_replicated(params, x, cfg: ModelConfig, mesh, rules):
             x_spec,
         ),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(params["router"], params["gate"], params["up"], params["down"], x)
 

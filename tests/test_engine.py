@@ -119,7 +119,7 @@ def test_pipelined_data_parallel_needs_devices():
 
 
 def test_fused_schedule_uses_pallas_cell():
-    """The fused schedule resolves cleanly (interpret fallback off-TPU) and
+    """The fused schedule resolves cleanly (interpreted on CPU) and
     keeps the sequential Eq-1 accounting (layer-major walk)."""
     cfg = get_config("lstm-ae-f32-d2")
     engine = build_engine(cfg, "fused")
@@ -310,12 +310,12 @@ print("ENGINE_PIPELINE_OK")
 
 
 def test_pipelined_engine_multi_device():
-    """The real pipelined path (internal mesh + stage params, incl. 2-way
-    data parallelism — the jit-split regression case) on 8 emulated devices
-    in a subprocess (device count is process-global)."""
+    """The real pipelined path (internal mesh + stage params traced into
+    one program, incl. 2-way data parallelism) on 8 emulated devices in a
+    subprocess (device count is process-global)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # emulated host devices, never the chip
     out = subprocess.run(
         [sys.executable, "-c", _MULTI_DEVICE_SCRIPT], env=env,
         capture_output=True, text=True, timeout=600,

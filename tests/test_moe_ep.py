@@ -57,7 +57,7 @@ print("MOE_EP_OK", gnorm)
 def test_moe_ep_matches_scatter():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # emulated host devices, never the chip
     out = subprocess.run(
         [sys.executable, "-c", _SCRIPT], env=env, capture_output=True,
         text=True, timeout=600,

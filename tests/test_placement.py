@@ -311,7 +311,7 @@ def test_sharded_gateway_multi_device():
     per-device telemetry, block padding, and the data_parallel shim."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # emulated host devices, never the chip
     out = subprocess.run(
         [sys.executable, "-c", _SHARDED_SCRIPT], env=env,
         capture_output=True, text=True, timeout=600,

@@ -76,12 +76,12 @@ def test_collective_parse_psum():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax.sharding import AxisType
         from repro.roofline.hlo_cost import analyze_hlo
-        mesh = jax.make_mesh((4,), ("x",))
+        mesh = jax.make_mesh((4,), ("x",), (AxisType.Auto,))
         def f(a):
             return jax.lax.psum(a, "x")
-        g = shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P(), check_rep=False)
+        g = jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P(), check_vma=False)
         text = jax.jit(g).lower(jax.ShapeDtypeStruct((64, 128), jnp.float32)).compile().as_text()
         t = analyze_hlo(text)
         print("COLL", int(t.coll_bytes), t.coll_by_op)

@@ -100,7 +100,7 @@ def test_pipelined_forward_multi_device():
     (device count is process-global, so tests keep their single device)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # emulated host devices, never the chip
     out = subprocess.run(
         [sys.executable, "-c", _PIPELINE_SCRIPT], env=env,
         capture_output=True, text=True, timeout=600,
