@@ -18,6 +18,10 @@ Each rule here flags a way that contract silently erodes:
           check-then-act sequences interleave
   ASY205  fork-method multiprocessing in a module that imports JAX —
           forked XLA runtime state hangs or corrupts silently
+  ASY206  a ``Telemetry.span`` or ``TraceAnnotation`` held across an
+          ``await`` — while it is suspended, other coroutines' spans open
+          and close on the same thread, so the span's time and the
+          profiler's nesting both take in their work
 
 Scope: ``gateway/`` and ``obs/`` (the modules that own threads, loops
 and processes).
@@ -28,7 +32,7 @@ import ast
 from typing import Iterable, Optional
 
 from repro.analysis.engine import (
-    FileContext, Finding, Rule, call_name, const_str, dotted_name,
+    FileContext, Finding, Rule, call_name, const_str, dotted_name, walk_scoped,
 )
 
 _TARGETS = (
@@ -133,6 +137,35 @@ def check_lock_across_await(ctx: FileContext) -> Iterable[Finding]:
                         f"or use asyncio.Lock",
                     )
                     break
+
+
+def _is_span(expr: ast.AST) -> bool:
+    """``<telemetry>.span(...)`` or ``TraceAnnotation(...)``."""
+    if not isinstance(expr, ast.Call):
+        return False
+    if isinstance(expr.func, ast.Attribute) and expr.func.attr == "span":
+        return True
+    return call_name(expr).rsplit(".", 1)[-1] == "TraceAnnotation"
+
+
+def check_span_across_await(ctx: FileContext) -> Iterable[Finding]:
+    for fn in _iter_async_defs(ctx.tree):
+        for node in _walk_same_async(fn):
+            if not isinstance(node, ast.With) or \
+                    not any(_is_span(item.context_expr) for item in node.items):
+                continue
+            # awaits of this coroutine only: a def nested in the block
+            # does not suspend it
+            if any(isinstance(sub, (ast.Await, ast.AsyncFor, ast.AsyncWith))
+                   for sub in walk_scoped(node, into_functions=False)):
+                yield ctx.finding(
+                    "ASY206", node,
+                    f"span held across `await` in `async def {fn.name}`: "
+                    f"while it is suspended other coroutines' spans open "
+                    f"and close on this thread, so its time and the "
+                    f"profiler's nesting take in their work — close the "
+                    f"span before awaiting",
+                )
 
 
 _TASK_SPAWNERS = ("create_task", "ensure_future")
@@ -369,4 +402,6 @@ FILE_RULES = [
          check_unlocked_shared_dict, _TARGETS),
     Rule("ASY205", "fork-method multiprocessing with JAX",
          check_fork_multiprocessing, _TARGETS),
+    Rule("ASY206", "profiler span held across await",
+         check_span_across_await, _TARGETS),
 ]

@@ -245,30 +245,33 @@ class SessionPool:
         """
         if not inputs:
             return {}
-        t0 = self.telemetry.now()
-        slots = [self._require(sid) for sid in inputs]
-        x = np.zeros((self._block, self.features), np.float32)
-        mask = np.zeros((self._block,), bool)
-        for sid, slot in zip(inputs, slots):
-            sample = np.asarray(inputs[sid], np.float32)
-            if sample.shape != (self.features,):
-                raise ValueError(
-                    f"stream {sid!r}: expected sample shape ({self.features},), "
-                    f"got {sample.shape}"
+        tel = self.telemetry
+        with tel.span("pool.step", "pool_step_ms"):
+            with tel.span("pool.assemble", "pool_assemble_ms"):
+                slots = [self._require(sid) for sid in inputs]
+                x = np.zeros((self._block, self.features), np.float32)
+                mask = np.zeros((self._block,), bool)
+                for sid, slot in zip(inputs, slots):
+                    sample = np.asarray(inputs[sid], np.float32)
+                    if sample.shape != (self.features,):
+                        raise ValueError(
+                            f"stream {sid!r}: expected sample shape "
+                            f"({self.features},), got {sample.shape}"
+                        )
+                    x[slot] = sample
+                    mask[slot] = True
+            with tel.span("pool.launch", "pool_launch_ms"):
+                # dispatch of the step and of the error read's three ops;
+                # JAX returns without waiting for their results
+                self._state, self._sq_sum, self._steps = self._pool_step(
+                    self.engine._require_params(), jnp.asarray(x), self._state,
+                    jnp.asarray(mask), self._sq_sum, self._steps,
                 )
-            x[slot] = sample
-            mask[slot] = True
-        self._state, self._sq_sum, self._steps = self._pool_step(
-            self.engine._require_params(), jnp.asarray(x), self._state,
-            jnp.asarray(mask), self._sq_sum, self._steps,
-        )
-        self.telemetry.record_pool_step(len(slots), self.capacity)
-        errs = np.asarray(self.errors())
-        # errs forced the device round-trip, so this wall time covers the
-        # full assemble + compiled-step + readback path of one pool step
-        self.telemetry.observe_stage(
-            "pool_step_ms", (self.telemetry.now() - t0) * 1e3
-        )
+                errors = self.errors()
+            tel.record_pool_step(len(slots), self.capacity)
+            with tel.span("pool.readback", "pool_readback_ms"):
+                # waits for the errors and copies them to the host
+                errs = np.asarray(errors)
         return {sid: float(errs[slot]) for sid, slot in zip(inputs, slots)}
 
     # -- durability export / restore --------------------------------------

@@ -278,69 +278,76 @@ class MicroBatcher:
         unconditionally — an engine failure mid-flush fails their tickets
         (error state + ``queue.failed``) instead of leaking queue depth and
         leaving them unresolved forever (the overload-wedge bug)."""
+        with self.telemetry.span("batch.flush"):
+            return self._flush(tb)
+
+    def _flush(self, tb: int) -> int:
         pending = self._buckets[tb]
         take, self._buckets[tb] = pending[: self.max_batch], pending[self.max_batch:]
         if not take:
             return 0
         n = len(take)
+        tel = self.telemetry
         # the take is out of the queue from here on, success or failure
         self._depth -= n
         self.telemetry.gauge("queue.depth", self._depth)
         t_flush = self._clock()
+        useful = 0
         try:
-            # fixed (lanes, tb, F) shape: one compile per bucket, ever
-            # (lanes == max_batch rounded to a per-device multiple)
-            pad = self._pad.get(tb)
-            if pad is None:
-                pad = self._pad[tb] = (
-                    np.zeros((self.lanes, tb, self.features), np.float32),
-                    np.ones((self.lanes,), np.int32),
+            with tel.span("batch.assemble", "assemble_ms") as assemble:
+                # fixed (lanes, tb, F) shape: one compile per bucket, ever
+                # (lanes == max_batch rounded to a per-device multiple)
+                pad = self._pad.get(tb)
+                if pad is None:
+                    pad = self._pad[tb] = (
+                        np.zeros((self.lanes, tb, self.features), np.float32),
+                        np.ones((self.lanes,), np.int32),
+                    )
+                x, lengths = pad
+                for i, (arr, _) in enumerate(take):
+                    ti = arr.shape[0]
+                    x[i, :ti] = arr
+                    # zero only the tail this row exposes — rows >= n keep a
+                    # previous flush's data but their lengths are reset to 1
+                    # below, so they are padding lanes and masked regardless
+                    x[i, ti:] = 0.0
+                    lengths[i] = ti
+                    useful += ti
+                lengths[n:] = 1
+            with tel.span("batch.compute", "compute_ms") as compute:
+                scores = np.asarray(
+                    self.engine.score_masked({"series": x, "lengths": lengths})
                 )
-            x, lengths = pad
-            for i, (arr, _) in enumerate(take):
-                ti = arr.shape[0]
-                x[i, :ti] = arr
-                # zero only the tail this row exposes — rows >= n keep a
-                # previous flush's data but their lengths are reset to 1
-                # below, so they are padding lanes and masked regardless
-                x[i, ti:] = 0.0
-                lengths[i] = ti
-            lengths[n:] = 1
-            t_assembled = self._clock()
-            scores = np.asarray(
-                self.engine.score_masked({"series": x, "lengths": lengths})
-            )
         except Exception as exc:
             self.telemetry.count("queue.failed", n)
             for _, ticket in take:
                 ticket._fail(exc)
             return 0
         now = self._clock()
-        assemble_ms = (t_assembled - t_flush) * 1e3
-        compute_ms = (now - t_assembled) * 1e3
         oldest_wait_ms = (now - take[0][1].t_submit) * 1e3
-        tel = self.telemetry
-        tel.observe_stage("assemble_ms", assemble_ms)
-        tel.observe_stage("compute_ms", compute_ms)
-        # per-ticket stage records resolve their histograms once per
-        # flush, not once per ticket — this loop is the score hot path
-        wait_hist = tel.histograms.get("queue_wait_ms") if tel.detail else None
-        if tel.detail and wait_hist is None:
-            wait_hist = tel.histograms["queue_wait_ms"] = Histogram()
-        req_record = tel.request_histogram.record
-        for i, (_, ticket) in enumerate(take):
-            queue_wait_ms = (t_flush - ticket.t_submit) * 1e3
-            ticket.stage_ms = {
-                "queue_wait": queue_wait_ms,
-                "assemble": assemble_ms,
-                "compute": compute_ms,
-            }
-            if wait_hist is not None:
-                wait_hist.record(queue_wait_ms)
-            req_record((now - ticket.t_submit) * 1e3)
-            ticket._resolve(float(scores[i]))
+        with tel.span("batch.resolve", "resolve_ms"):
+            # per-ticket stage records resolve their histograms once per
+            # flush, not once per ticket — this loop is the score hot path
+            wait_hist = tel.histograms.get("queue_wait_ms") if tel.detail else None
+            if tel.detail and wait_hist is None:
+                wait_hist = tel.histograms["queue_wait_ms"] = Histogram()
+            req_record = tel.request_histogram.record
+            for i, (_, ticket) in enumerate(take):
+                queue_wait_ms = (t_flush - ticket.t_submit) * 1e3
+                ticket.stage_ms = {
+                    "queue_wait": queue_wait_ms,
+                    "assemble": assemble.ms,
+                    "compute": compute.ms,
+                }
+                if wait_hist is not None:
+                    wait_hist.record(queue_wait_ms)
+                req_record((now - ticket.t_submit) * 1e3)
+                ticket._resolve(float(scores[i]))
         tel.count("queue.completed", n)
         tel.record_batch(n, self.lanes, oldest_wait_ms)
+        # padded timesteps: true window lengths against the rows computed
+        tel.count("batch.rows_useful", useful)
+        tel.count("batch.rows_computed", self.lanes * tb)
         if self.placement.is_sharded:
             # real rows pack from lane 0, so contiguous-block sharding puts
             # device d's fill at rows [d*lpd, (d+1)*lpd) — gauge it so

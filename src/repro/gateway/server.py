@@ -353,18 +353,20 @@ class GatewayServer:
         # inside pump(); this guard only covers bookkeeping bugs so the
         # pump itself can never die and wedge the queue.
         while True:
-            try:
-                self.gateway.pump()
-                if self.gateway.durability is not None:
-                    # cadence snapshots ride the pump: skip (never block)
-                    # while the previous background write is in flight
-                    self.gateway.durability.maybe_snapshot()
-                if self.gateway.control is not None:
-                    # control ticks ride the pump too: the controller
-                    # rate-limits itself via its tick interval
-                    self.gateway.control.maybe_tick()
-            except Exception:
-                logger.exception("background pump failed; queue state kept")
+            with self.gateway.telemetry.span("pump.tick"):
+                try:
+                    self.gateway.pump()
+                    if self.gateway.durability is not None:
+                        # cadence snapshots ride the pump: skip (never
+                        # block) while the previous background write is
+                        # in flight
+                        self.gateway.durability.maybe_snapshot()
+                    if self.gateway.control is not None:
+                        # control ticks ride the pump too: the controller
+                        # rate-limits itself via its tick interval
+                        self.gateway.control.maybe_tick()
+                except Exception:
+                    logger.exception("background pump failed; queue state kept")
             await asyncio.sleep(self.pump_interval_s)
 
     # -- connection handling ----------------------------------------------
@@ -543,10 +545,11 @@ class _Connection:
             payload["id"] = rid
         if self.writer.is_closing():
             return
-        try:
-            self.writer.write((json.dumps(payload) + "\n").encode())
-        except Exception:
-            logger.exception("conn %d: response write failed", self.conn_id)
+        with self.gateway.telemetry.span("wire.encode"):
+            try:
+                self.writer.write((json.dumps(payload) + "\n").encode())
+            except Exception:
+                logger.exception("conn %d: response write failed", self.conn_id)
 
     def send_frame(
         self, opcode: int, rid: int, meta: Optional[dict] = None,
@@ -554,12 +557,13 @@ class _Connection:
     ) -> None:
         if self.writer.is_closing():
             return
-        try:
-            self.writer.write(
-                wire.pack_frame(opcode, rid, meta=meta, data=data, flags=flags)
-            )
-        except Exception:
-            logger.exception("conn %d: frame write failed", self.conn_id)
+        with self.gateway.telemetry.span("wire.encode"):
+            try:
+                self.writer.write(
+                    wire.pack_frame(opcode, rid, meta=meta, data=data, flags=flags)
+                )
+            except Exception:
+                logger.exception("conn %d: frame write failed", self.conn_id)
 
     # -- dispatch ----------------------------------------------------------
 
@@ -567,8 +571,11 @@ class _Connection:
         # server-side wire cost per request: JSON decode + handler +
         # response encode/queue (the transport tax minus kernel + client
         # time) — the ``wire_ms`` stage histogram when detail is on
+        with self.gateway.telemetry.span("wire.frame", "wire_ms"):
+            self._dispatch_line(line)
+
+    def _dispatch_line(self, line: bytes) -> None:
         tel = self.gateway.telemetry
-        t_in = tel.now() if tel.detail else 0.0
         tel.count("wire.req_json")
         if not self._counted:
             self._counted = True
@@ -590,15 +597,16 @@ class _Connection:
             handler(req, rid)
         except Exception as exc:  # per-request isolation: one bad request
             self.send(_error_payload(op, exc), rid)  # never drops the conn
-        if tel.detail:
-            tel.observe_stage("wire_ms", (tel.now() - t_in) * 1e3)
 
     def dispatch_frame(self, frame: wire.Frame) -> None:
         """Binary-mode request dispatch.  SCORE/STEP get dedicated
         raw-float32 handlers; every other opcode rebuilds the JSON-era
         request dict from the frame's meta and reuses ``_op_*``."""
+        with self.gateway.telemetry.span("wire.frame", "wire_ms"):
+            self._dispatch_frame(frame)
+
+    def _dispatch_frame(self, frame: wire.Frame) -> None:
         tel = self.gateway.telemetry
-        t_in = tel.now() if tel.detail else 0.0
         tel.count("wire.req_bp1")
         rid = frame.req_id
         op = wire.NAME_BY_OPCODE.get(frame.opcode)
@@ -611,7 +619,8 @@ class _Connection:
             )
             return
         try:
-            meta, data = wire.split_payload(frame.payload)
+            with tel.span("wire.decode"):
+                meta, data = wire.split_payload(frame.payload)
         except wire.WireProtocolError as exc:
             # the length field was honest (we read a complete frame), so
             # stream alignment holds: answer an error, keep the conn
@@ -631,8 +640,6 @@ class _Connection:
                 getattr(self, f"_op_{op}")(req, rid)
         except Exception as exc:  # same per-request isolation as dispatch()
             self.send(_error_payload(op, exc), rid)
-        if tel.detail:
-            tel.observe_stage("wire_ms", (tel.now() - t_in) * 1e3)
 
     def _frame_score(self, meta: dict, data, rid: int) -> None:
         """A SCORE frame: ``n`` windows of shape ``(t, f)`` as one raw
@@ -663,7 +670,8 @@ class _Connection:
                 wire.OP_SCORE, rid, meta={"ok": True, "op": "score", "n": 0}
             )
             return
-        windows = wire.decode_f32(data, (n, t, f))
+        with self.gateway.telemetry.span("wire.decode"):
+            windows = wire.decode_f32(data, (n, t, f))
         tid = meta.get("trace")
         span = (self.gateway.tracer.start("score", trace_id=str(tid))
                 if tid is not None and n == 1 else None)
@@ -704,7 +712,8 @@ class _Connection:
             raise ValueError(
                 f"expected sample shape ({feats},), got ({count},)"
             )
-        xs = wire.decode_f32(data, (k, feats))
+        with self.gateway.telemetry.span("wire.decode"):
+            xs = wire.decode_f32(data, (k, feats))
         if self.stream_id is None:
             dur = self.gateway.durability
             if dur is not None:

@@ -12,10 +12,20 @@ Latency lives in fixed-boundary log-linear histograms
 per-worker histograms serialize through ``stats()`` as sparse bucket
 dicts and SUM exactly across workers, so a multi-worker front reports
 true front-wide percentiles.  Besides the request-latency histogram
-(``request_ms``) there are per-stage histograms (``queue_wait_ms``,
-``batch_wait_ms``, ``assemble_ms``, ``compute_ms``, ``wire_ms``,
-``pool_step_ms``) decomposing where wire latency goes; stage recording
-is gated by ``detail`` so the overhead benchmark can price it.
+(``request_ms``) there are per-stage histograms decomposing where wire
+latency goes: ``wire_ms`` (one request through the transport),
+``queue_wait_ms`` and ``batch_wait_ms`` (the micro-batcher's queue),
+``assemble_ms``, ``compute_ms`` and ``resolve_ms`` (one flush: host
+assembly, the masked score program with its readback, answering the
+tickets), and ``pool_step_ms`` with its parts ``pool_assemble_ms``,
+``pool_launch_ms`` and ``pool_readback_ms`` (one session-pool step).
+Stage recording is gated by ``detail`` so the overhead benchmark can
+price it.
+
+Stages are timed by :meth:`Telemetry.span`, which also writes the block
+into a running ``jax.profiler`` trace as a host annotation named
+``<layer>.<stage>`` (``wire.frame``, ``pool.launch``, ``batch.flush``,
+...), so the device trace's clock reaches the program's own stages.
 
 Scalar gauges and vector gauges (per-mesh-shard values) live in separate
 maps — ``gauges`` is honestly ``dict[str, float]`` and ``gauge_vecs``
@@ -29,14 +39,61 @@ injectable so tests control time.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from collections import defaultdict
-from typing import Callable, Iterable, Tuple
+from typing import Callable, Iterable, Optional, Tuple
 
 from repro.obs.histogram import Histogram
 
 # the request-latency histogram's key in ``Telemetry.histograms``
 REQUEST_HIST = "request_ms"
+
+# jax.profiler.TraceAnnotation, imported at the first span: a process
+# that only merges stats (a WorkerFront supervisor) never imports JAX
+_TraceAnnotation = None
+
+
+def _annotation(name: str):
+    """A host annotation named ``name`` for the profiler's trace, or None
+    when the profiler's recorder is off (no trace running)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name) if _TraceAnnotation.is_enabled() else None
+
+
+# what a span without a stage is while no trace runs: nothing to record
+_IDLE_SPAN = contextlib.nullcontext()
+
+
+class StageSpan:
+    """One synchronous block timed by :meth:`Telemetry.span`; ``ms`` is
+    its duration once it has exited."""
+
+    __slots__ = ("_tel", "_stage", "_note", "_t0", "ms")
+
+    def __init__(self, tel: "Telemetry", note, stage: Optional[str]):
+        self._tel = tel
+        self._stage = stage
+        self._note = note
+        self.ms = 0.0
+
+    def __enter__(self) -> "StageSpan":
+        if self._note is not None:
+            self._note.__enter__()
+        self._t0 = self._tel._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.ms = (self._tel._clock() - self._t0) * 1e3
+        if self._note is not None:
+            self._note.__exit__(*exc)
+        if self._stage is not None:
+            self._tel.observe_stage(self._stage, self.ms)
+
 
 # counters whose short-horizon rates feed the control plane (sliding
 # window, not lifetime averages — see _RateWindow)
@@ -171,6 +228,23 @@ class Telemetry:
         obs_overhead benchmark's 'off' arm)."""
         if self.detail:
             self.observe(name, ms)
+
+    def span(self, name: str, stage: Optional[str] = None):
+        """Time a synchronous block as the span ``name`` (``<layer>.<stage>``).
+
+        The block is a ``jax.profiler.TraceAnnotation`` on the host plane
+        of any running profiler trace.  With ``stage`` it is a
+        :class:`StageSpan`, timed on this telemetry's clock, whose
+        duration is also a sample of that stage histogram (when
+        ``detail`` is on), also when the block raises; without one and
+        with no trace running, it does nothing.  Never hold a span across
+        an ``await``: another coroutine's spans would interleave with it
+        on the same thread.
+        """
+        note = _annotation(name)
+        if note is None and stage is None:
+            return _IDLE_SPAN
+        return StageSpan(self, note, stage)
 
     def observe_latency_ms(self, ms: float) -> None:
         self.observe(REQUEST_HIST, ms)

@@ -104,7 +104,6 @@ class Tracer:
         self.sample_every = sample_every
         self._seq = itertools.count()
         self._finished = 0
-        self._emitted = 0
         # pid cached at construction: new_id() sits on the traced hot
         # path and os.getpid() is a syscall per call; workers build their
         # tracer post-spawn so the cached pid is the serving process's
@@ -124,13 +123,5 @@ class Tracer:
         self._finished += 1
         if self.events is not None and self.events.enabled \
                 and (self._finished - 1) % self.sample_every == 0:
-            self._emitted += 1
             self.events.emit("span", **span.to_dict())
         return span
-
-    def describe(self) -> dict:
-        return {
-            "finished": self._finished,
-            "emitted": self._emitted,
-            "sample_every": self.sample_every,
-        }

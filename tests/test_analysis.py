@@ -32,6 +32,8 @@ CASES = [
     ("ASY203", ["asy203.py"], {("ASY203", 2)}),
     ("ASY204", ["asy204.py"], {("ASY204", 11)}),
     ("ASY205", ["asy205.py"], {("ASY205", 7), ("ASY205", 8)}),
+    # the span around pump() closes before the await in pump_ok: silent
+    ("ASY206", ["asy206.py"], {("ASY206", 5)}),
     # wire contract rules need both roles in the file set; the findings
     # anchor in the consumer (client) file
     ("CON301", ["wire_client.py", "wire_server.py"],
