@@ -265,17 +265,24 @@ class DurableSessions:
 
     def step(self, sid: str, x) -> tuple[float, int, str]:
         """One pool step for ``sid``; returns ``(running_error, seq,
-        token)``.  The token is re-minted every ``token_refresh_steps``
-        steps (and on epoch change); in between the previous one is
-        returned — equally resumable, since replay position comes from
-        the client's buffer, not the token."""
+        token)``."""
         running = self.gateway.step({sid: x})[sid]
+        return (running, *self.advance(sid))
+
+    def advance(self, sid: str) -> tuple[int, str]:
+        """The bookkeeping of one pool step ``sid`` has taken; returns
+        ``(seq, token)``.  The transport steps its streams together
+        (``StepCoalescer``) and calls this once per stepped sample, in the
+        stream's own order.  The token is re-minted every
+        ``token_refresh_steps`` steps (and on epoch change); in between
+        the previous one is returned — equally resumable, since replay
+        position comes from the client's buffer, not the token."""
         seq = self._seq[sid] = self._seq.get(sid, 0) + 1
         cached = self._tok_cache.get(sid)
         if cached is not None and cached[0] == self.epoch \
                 and seq % self.token_refresh_steps:
-            return running, seq, cached[1]
-        return running, seq, self._mint(sid, seq)
+            return seq, cached[1]
+        return seq, self._mint(sid, seq)
 
     def close(self, sid: str) -> float:
         """Explicit close: evict AND forget — the session leaves the next

@@ -26,7 +26,9 @@ LSTM cell, and unmasked slots carry their state unchanged.
 """
 from __future__ import annotations
 
-from typing import Hashable, Mapping, Optional
+import logging
+from collections import deque
+from typing import Callable, Hashable, Mapping, Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +36,8 @@ import numpy as np
 
 from repro.engine.base import Engine
 from repro.gateway.telemetry import Telemetry
+
+logger = logging.getLogger(__name__)
 
 
 class PoolFullError(RuntimeError):
@@ -153,6 +157,9 @@ class SessionPool:
     @property
     def active(self) -> int:
         return len(self._slot_of)
+
+    def __contains__(self, stream_id: Hashable) -> bool:
+        return stream_id in self._slot_of
 
     @property
     def resident(self) -> tuple:
@@ -338,3 +345,104 @@ class SessionPool:
               if self.placement.is_sharded else "")
         return (f"SessionPool(capacity={self.capacity}, active={self.active}, "
                 f"schedule={self.engine.schedule.tag}{pl})")
+
+
+class _Pending:
+    """One frame's samples of one stream, waiting in a :class:`StepCoalescer`."""
+
+    __slots__ = ("xs", "errors", "next", "on_done")
+
+    def __init__(self, xs, on_done):
+        self.xs = xs
+        self.errors = np.zeros(len(xs), np.float32)
+        self.next = 0  # index of the sample the next step takes
+        self.on_done = on_done
+
+    def finish(self, exc: Optional[BaseException] = None) -> None:
+        try:
+            self.on_done(self.errors[:self.next], exc)
+        except Exception:
+            logger.exception("step completion callback raised")
+
+
+class StepCoalescer:
+    """STEP samples of every stream, stepped together: one pool step per
+    event-loop pass instead of one per sample.
+
+    :meth:`submit` queues a frame's samples on its stream's FIFO and, on
+    the first submit since the last flush, hands :meth:`flush` to
+    ``schedule`` (the server passes ``loop.call_soon``, so every frame read
+    in the same pass of the loop is queued by the time the flush runs).  A
+    flush repeats until every FIFO is empty: the head sample of each
+    stream that has one, ONE :meth:`SessionPool.step` over all of them,
+    each running error handed back.  A step carries whatever arrived —
+    nothing waits on a timer, so a lone stream steps alone as before — and
+    rows are independent through the cell (the contract above), so each
+    error is bit-for-bit what the stream sees stepped alone.  A frame of
+    ``k`` samples takes ``k`` steps; frames of one stream step in arrival
+    order.
+
+    ``on_done(errors, exc)`` is called once per frame with the running
+    errors of its samples that were stepped: all ``k`` and ``exc=None``
+    once the last is known.  An engine exception fails every frame of
+    that step with it (their remaining samples are dropped, the other
+    streams step on), and a stream evicted while its samples wait is
+    skipped, its frames failed with :class:`UnknownStreamError`.
+    """
+
+    def __init__(self, pool: SessionPool,
+                 schedule: Callable[[Callable[[], None]], object]):
+        self.pool = pool
+        self._schedule = schedule
+        self._queues: dict[Hashable, deque] = {}
+        self._due = False
+
+    @property
+    def pending(self) -> int:
+        """Streams with samples waiting."""
+        return len(self._queues)
+
+    def submit(self, stream_id: Hashable, xs: "np.ndarray",
+               on_done: Callable) -> None:
+        """Queue the ``(k, F)`` samples ``xs`` of a resident stream."""
+        self._queues.setdefault(stream_id, deque()).append(_Pending(xs, on_done))
+        if not self._due:
+            self._due = True
+            self._schedule(self.flush)
+
+    def flush(self) -> None:
+        """Step until no sample waits (nothing to do when none does)."""
+        self._due = False
+        while self._queues:
+            heads = {}
+            for sid in list(self._queues):
+                if sid in self.pool:
+                    heads[sid] = self._queues[sid][0]
+                    continue
+                gone = UnknownStreamError(
+                    f"stream {sid!r} was evicted while its samples waited")
+                for job in self._queues.pop(sid):
+                    job.finish(gone)
+            if not heads:
+                return
+            try:
+                running = self.pool.step(
+                    {sid: job.xs[job.next] for sid, job in heads.items()})
+            except Exception as exc:
+                # the batcher's ticket semantics: this step's frames answer
+                # the error, every other sample still steps
+                for sid in heads:
+                    self._pop(sid).finish(exc)
+                continue
+            for sid, job in heads.items():
+                job.errors[job.next] = running[sid]
+                job.next += 1
+                if job.next == len(job.xs):
+                    self._pop(sid).finish()
+
+    def _pop(self, stream_id: Hashable) -> _Pending:
+        queue = self._queues[stream_id]
+        job = queue.popleft()
+        if not queue:
+            del self._queues[stream_id]
+        return job

@@ -110,6 +110,17 @@ contract; JAX calls block the loop for one step/flush at a time, which
 is the micro-batching granularity anyway.  ``drain()`` is the graceful
 shutdown: stop accepting, flush the queue so every pending ticket
 answers, then evict sessions and close connections.
+
+STEP samples (bp1 frames and the JSON ``step`` op, durable sessions
+too) do not step inside their handler: they queue on the server's
+:class:`~repro.gateway.pool.StepCoalescer`, whose flush runs once the
+current pass of the event loop has read every frame that arrived, and
+steps the head sample of every waiting stream in ONE pool step.  A
+frame is answered when its last sample's running error is known (a
+durable frame's ``seq``/``token`` are assigned then, in step order).
+``close``, ``resume``, ``snapshot``, ``recalibrate``, ``stats``,
+connection teardown and ``drain()`` flush the coalescer before they act,
+so each sees every step sent before it.
 """
 from __future__ import annotations
 
@@ -123,6 +134,7 @@ from typing import Callable, Optional
 import numpy as np
 
 from repro.gateway import AnomalyGateway, wire
+from repro.gateway.pool import StepCoalescer
 
 logger = logging.getLogger(__name__)
 
@@ -197,6 +209,11 @@ class GatewayServer:
         self._writers: set = set()
         self._conn_seq = 0
         self._draining = False
+        # STEP samples of every connection, stepped together once per pass
+        # of the event loop (handlers and flush share the loop's thread)
+        self.steps = StepCoalescer(
+            gateway.pool, lambda flush: asyncio.get_running_loop().call_soon(flush)
+        )
         # thread-mode bookkeeping (start_in_thread)
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
@@ -235,6 +252,7 @@ class GatewayServer:
             await asyncio.gather(self._pump_task, return_exceptions=True)
             self._pump_task = None
         try:
+            self.steps.flush()  # every queued STEP answers
             self.gateway.flush()  # completes pending tickets -> responses go out
         except Exception:
             logger.exception("drain: final flush failed")
@@ -693,9 +711,10 @@ class _Connection:
     def _frame_step(self, meta: dict, data, rid: int) -> None:
         """A STEP frame: ``t`` consecutive samples for this connection's
         session in one frame (amortizes the round-trip; the response
-        returns every intermediate running error).  Durable sessions get
-        their ``seq``/``token`` from the LAST sample, which is exactly
-        what a replaying client needs."""
+        returns every intermediate running error).  The samples take
+        ``t`` coalesced pool steps.  Durable sessions get their
+        ``seq``/``token`` from the LAST sample, which is exactly what a
+        replaying client needs."""
         feats = self.gateway.pool.features
         if "x" in meta and not len(data):
             # JSON-style request tunneled through a generic meta frame
@@ -714,33 +733,20 @@ class _Connection:
             )
         with self.gateway.telemetry.span("wire.decode"):
             xs = wire.decode_f32(data, (k, feats))
-        if self.stream_id is None:
-            dur = self.gateway.durability
-            if dur is not None:
-                self.stream_id, _ = dur.admit()
-            else:
-                self.session_seq += 1
-                sid = ("conn", self.conn_id, self.session_seq)
-                self.gateway.admit(sid)
-                self.stream_id = sid
-        errors = np.zeros(k, np.float32)
-        seq = token = None
-        dur = self._durable
-        for i in range(k):
-            if dur is not None:
-                running, seq, token = dur.step(self.stream_id, xs[i])
-            else:
-                running = self.gateway.step({self.stream_id: xs[i]})[self.stream_id]
-            errors[i] = running
-        meta_out = {"ok": True, "op": "step", "t": k,
-                    "running_error": float(errors[-1])}
-        if token is not None:
-            meta_out["seq"] = seq
-            meta_out["token"] = token
-        threshold = self.gateway.threshold
-        if threshold is not None:
-            meta_out["alert"] = [bool(e > threshold) for e in errors.tolist()]
-        self.send_frame(wire.OP_STEP, rid, meta=meta_out, data=errors.tobytes())
+        self._open_session()
+
+        def answer(errors, seq, token) -> None:
+            meta_out = {"ok": True, "op": "step", "t": k,
+                        "running_error": float(errors[-1])}
+            if token is not None:
+                meta_out["seq"] = seq
+                meta_out["token"] = token
+            threshold = self.gateway.threshold
+            if threshold is not None:
+                meta_out["alert"] = [bool(e > threshold) for e in errors.tolist()]
+            self.send_frame(wire.OP_STEP, rid, meta=meta_out, data=errors.tobytes())
+
+        self._queue_steps(xs, rid, answer)
 
     def _alert_field(self, payload: dict, value: float) -> dict:
         threshold = self.gateway.threshold
@@ -761,6 +767,40 @@ class _Connection:
             return dur
         return None
 
+    def _open_session(self) -> None:
+        """Admit this connection's stream on its first step (a full pool
+        raises ``PoolFullError`` -> error response)."""
+        if self.stream_id is not None:
+            return
+        dur = self.gateway.durability
+        if dur is not None:
+            self.stream_id, _ = dur.admit()
+        else:
+            self.session_seq += 1
+            sid = ("conn", self.conn_id, self.session_seq)
+            self.gateway.admit(sid)
+            self.stream_id = sid
+
+    def _queue_steps(self, xs, rid, answer) -> None:
+        """Queue the session's ``(k, F)`` samples on the server's step
+        coalescer.  Once the last one's running error is known,
+        ``answer(errors, seq, token)`` writes the response (``seq`` and
+        ``token`` are None unless the session is durable); a failed step
+        answers its error instead."""
+        sid, dur = self.stream_id, self._durable
+
+        def done(errors, exc) -> None:
+            seq = token = None
+            if dur is not None:
+                for _ in errors:  # one per stepped sample, in step order
+                    seq, token = dur.advance(sid)
+            if exc is not None:
+                self.send(_error_payload("step", exc), rid)
+            else:
+                answer(errors, seq, token)
+
+        self.server.steps.submit(sid, xs, done)
+
     def _op_step(self, req: dict, rid) -> None:
         # optional tracing: a "trace" field opts this request into a span
         # (unknown to PR-3 peers, ignored by them — backward compatible)
@@ -773,32 +813,27 @@ class _Connection:
         feats = self.gateway.pool.features
         if x.shape != (feats,):
             raise ValueError(f"expected sample shape ({feats},), got {x.shape}")
-        dur = self.gateway.durability
-        if self.stream_id is None:
-            if dur is not None:
-                self.stream_id, _ = dur.admit()  # PoolFullError -> error resp
-            else:
-                self.session_seq += 1
-                sid = ("conn", self.conn_id, self.session_seq)
-                self.gateway.admit(sid)
-                self.stream_id = sid
+        self._open_session()
         if span is not None:
             span.mark("dispatch")
-        if self._durable is not None:
-            running, seq, token = self._durable.step(self.stream_id, x)
-            payload = {"ok": True, "op": "step", "running_error": running,
-                       "seq": seq, "token": token}
-        else:
-            running = self.gateway.step({self.stream_id: x})[self.stream_id]
+
+        def answer(errors, seq, token) -> None:
+            running = float(errors[-1])
             payload = {"ok": True, "op": "step", "running_error": running}
-        if span is not None:
-            span.mark("compute")
-            payload["trace"] = self.gateway.tracer.finish(span).to_wire()
-        self.send(self._alert_field(payload, running), rid)
+            if token is not None:
+                payload["seq"] = seq
+                payload["token"] = token
+            if span is not None:
+                span.mark("compute")
+                payload["trace"] = self.gateway.tracer.finish(span).to_wire()
+            self.send(self._alert_field(payload, running), rid)
+
+        self._queue_steps(x[None], rid, answer)
 
     def _op_close(self, req: dict, rid) -> None:
         if self.stream_id is None:
             raise ValueError("no open session on this connection (step first)")
+        self.server.steps.flush()  # the final score includes queued steps
         if self._durable is not None:
             final = self._durable.close(self.stream_id)  # forgotten: tokens die
         else:
@@ -817,6 +852,7 @@ class _Connection:
                 "this connection already carries a session; close it "
                 "before resuming another"
             )
+        self.server.steps.flush()
         out = dur.resume(req["token"])  # token errors -> dispatch error path
         self.stream_id = out["sid"]
         payload = {"ok": True, "op": "resume", "seq": out["seq"],
@@ -831,6 +867,7 @@ class _Connection:
         if self.stream_id is None:
             return
         try:
+            self.server.steps.flush()  # queued samples step before it leaves
             if self._durable is not None:
                 self._durable.suspend(self.stream_id)
             else:
@@ -895,6 +932,8 @@ class _Connection:
         task.add_done_callback(self._control_tasks.discard)
 
     def _op_recalibrate(self, req: dict, rid) -> None:
+        # steps sent before the swap are answered under the old values
+        self.server.steps.flush()
         kw = {}
         if "threshold" in req:
             kw["threshold"] = req["threshold"]
@@ -911,6 +950,7 @@ class _Connection:
         )
 
     def _op_stats(self, req: dict, rid) -> None:
+        self.server.steps.flush()
         provider = self.server.stats_provider
         if provider is None:
             self.send({"ok": True, "op": "stats",
@@ -926,6 +966,7 @@ class _Connection:
         dur = self.gateway.durability
         if dur is None:
             raise ValueError("durability is not enabled on this server")
+        self.server.steps.flush()
         out = dur.snapshot_now(wait=True)  # synchronous: callers use this
         self.send({"ok": True, "op": "snapshot", **out}, rid)  # as a barrier
 
