@@ -4,13 +4,18 @@ non-blocking sockets, as ``bench/traffic.py`` plans them.
     python bench/loadgen.py <spec.json>
 
 The spec (written by ``bench/run.py``) holds the mix, the seed, the chips,
-the model's input width, the server's address and the file the answers go
-to.  The process never imports JAX.  It talks to its parent over stdin
-and stdout:
+the model's configuration and its family adapter's file
+(``bench/families/``, which makes the frames' inputs), the server's
+address, the file the answers go to, and which part of the mix's
+connections this process drives (``part`` of ``parts``, a contiguous
+slice; the whole mix where the spec names none).  The process never
+imports JAX.  It talks to its parent over stdin and stdout:
 
-1. it opens every connection; each ``step`` connection sends its stream's
+1. it opens its connections; each ``step`` connection sends its stream's
    first frame, which admits the stream to the session pool, and waits
-   for the answer; then it prints ``READY``;
+   for the answer; a stream refused as ``PoolFullError`` (a front whose
+   kernel placed the connection on a full worker) reconnects and sends
+   that frame again.  Then it prints ``READY``;
 2. it reads ``GO <t0> <t1>`` (``time.monotonic`` instants, a clock shared
    by every process of the host), waits for ``t0`` and sends until
    ``t1``: a closed-loop connection keeps its frames in flight, an
@@ -39,9 +44,13 @@ import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-import series  # noqa: E402
+import families  # noqa: E402
 import traffic  # noqa: E402
 from bp1 import connect, wire  # noqa: E402
+
+
+#: connections a stream may try before its admission counts as failed
+READMIT_TRIES = 64
 
 
 class _Conn:
@@ -60,9 +69,12 @@ class LoadGen:
     def __init__(self, spec: dict):
         self.spec = spec
         self.seed = int(spec["seed"])
-        self.features = int(spec["features"])
+        self.config = spec["config"]
+        self.family = families.load_file(spec["family_file"])
         self.drain_s = float(spec.get("drain_s", 60.0))
-        self.plan = traffic.connections(spec["mix"], int(spec["chips"]))
+        plan = traffic.connections(spec["mix"], int(spec["chips"]))
+        parts, part = int(spec.get("parts", 1)), int(spec.get("part", 0))
+        self.plan = plan[part * len(plan) // parts:(part + 1) * len(plan) // parts]
         self.lengths = traffic.group_lengths(spec["mix"], self.seed)
         self.sel = selectors.DefaultSelector()
         self.conns: list[_Conn] = []
@@ -76,6 +88,7 @@ class LoadGen:
         self.window_sent = 0
         self._chunks: dict = {}     # stream -> (chunk index, samples)
         self._payloads: dict = {}   # window id -> SCORE payload
+        self._refused: list = []    # connections refused admission
 
     # -- request bodies ------------------------------------------------------
 
@@ -83,11 +96,11 @@ class LoadGen:
         """Samples ``t .. t + k - 1`` of the connection's stream."""
         stream, out = plan["stream"], []
         for j in range(t, t + k):
-            c, off = divmod(j, series.CHUNK)
+            c, off = divmod(j, self.family.CHUNK)
             got = self._chunks.get(stream)
             if got is None or got[0] != c:
-                got = (c, series.stream_chunk(self.seed, stream, c, self.features,
-                                              plan["anomaly_rate"]))
+                got = (c, self.family.stream_chunk(self.seed, stream, c, self.config,
+                                                   plan["anomaly_rate"]))
                 self._chunks[stream] = got
             out.append(got[1][off])
         return np.stack(out)
@@ -97,11 +110,10 @@ class LoadGen:
         payload = self._payloads.get(wid)
         if payload is None:
             length = int(self.lengths[plan["group"]][w])
-            x = series.window(self.seed, wid, length, self.features,
-                              plan["anomaly_rate"])
-            payload = wire.pack_payload(
-                dict(plan["meta"], n=1, t=length, f=self.features),
-                np.ascontiguousarray(x, "<f4").tobytes())
+            x = self.family.window(self.seed, wid, length, self.config,
+                                   plan["anomaly_rate"])
+            meta, data = self.family.score_frame(x)
+            payload = wire.pack_payload(dict(plan["meta"], **meta), data)
             self._payloads[wid] = payload
         return payload
 
@@ -111,8 +123,8 @@ class LoadGen:
         if plan["op"] == "step":
             k = plan["k"]
             t = conn.sent * k
-            data = np.ascontiguousarray(self._samples(plan, t, k), "<f4").tobytes()
-            payload = wire.pack_payload(dict(plan["meta"], t=k), data)
+            meta, data = self.family.step_frame(self._samples(plan, t, k))
+            payload = wire.pack_payload(dict(plan["meta"], **meta), data)
             return wire.OP_STEP, payload, [(plan["stream"], t + j) for j in range(k)]
         n_windows = len(self.lengths[plan["group"]])
         w = (plan["index"] + plan["conns"] * conn.sent) % n_windows
@@ -163,7 +175,10 @@ class LoadGen:
                 continue  # a connection-level notice, not an answer
             t_send, keys = sent
             values = np.frombuffer(data, "<f4")
-            if (flags & wire.FLAG_ERROR or not meta.get("ok", False)
+            if (not self.phase and meta.get("error") == "PoolFullError"
+                    and conn.plan["op"] == "step"):
+                self._refused.append(conn)
+            elif (flags & wire.FLAG_ERROR or not meta.get("ok", False)
                     or len(values) != len(keys)):
                 self.errors += 1
                 if len(self.error_msgs) < 5:
@@ -210,14 +225,16 @@ class LoadGen:
 
     # -- phases --------------------------------------------------------------
 
+    def _connect(self) -> socket.socket:
+        sock = connect(self.spec["host"], int(self.spec["port"]))
+        sock.setblocking(False)
+        return sock
+
     def open(self) -> None:
-        host, port = self.spec["host"], int(self.spec["port"])
         for plan in self.plan:
-            sock = connect(host, port)
-            sock.setblocking(False)
-            conn = _Conn(sock, plan)
+            conn = _Conn(self._connect(), plan)
             self.conns.append(conn)
-            self.sel.register(sock, selectors.EVENT_READ, conn)
+            self.sel.register(conn.sock, selectors.EVENT_READ, conn)
         now = time.monotonic()
         for conn in self.conns:
             if conn.plan["op"] == "step":
@@ -226,7 +243,26 @@ class LoadGen:
                 n_windows = len(self.lengths[conn.plan["group"]])
                 for w in range(conn.plan["index"], n_windows, conn.plan["conns"]):
                     self._window_payload(conn.plan, w)
-        self._pump(time.monotonic() + 600.0)
+        deadline = time.monotonic() + 600.0
+        self._pump(deadline)
+        for _ in range(READMIT_TRIES):
+            if not self._refused:
+                break
+            refused, self._refused = self._refused, []
+            for conn in refused:
+                # a new connection is placed anew by the front's kernel
+                self.sel.unregister(conn.sock)
+                conn.sock.close()
+                conn.sock = self._connect()
+                conn.rbuf.clear()
+                conn.wbuf.clear()
+                conn.sent = 0
+                self.sel.register(conn.sock, selectors.EVENT_READ, conn)
+                self._send_next(conn, time.monotonic())
+            self._pump(deadline)
+        if self._refused:
+            raise RuntimeError(f"{len(self._refused)} streams found no free "
+                               f"slot in {READMIT_TRIES} connections")
         if self._pending():
             raise RuntimeError("warm-up answers did not arrive")
 

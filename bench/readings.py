@@ -13,13 +13,17 @@ is a dict:
   warm-up, 1 window) and ``first`` (the frame's first value), on the load
   generator's clock (``bench/loadgen.py``);
 * ``stats0``, ``stats1``: the gateway's telemetry snapshots (the bp1
-  ``stats`` answer) at the window's start and end;
+  ``stats`` answer) at the window's start and end; a cell served by a
+  worker front has the front's (``WorkerFront.stats()``): counters summed
+  and histograms merged over the workers, and each worker's own snapshot
+  under ``per_worker``;
 * ``work``: useful work answered in the window, ``{"requests",
-  "row_timesteps", "flops", "bytes"}`` (``bench/flops.py``);
+  "row_timesteps", "flops", "bytes"}`` (FLOPs and bytes from the model
+  family's ``useful_work``, ``bench/families/``);
 * ``trace``: the reduced profiler trace of the traced part of the window
   (``bench/devtrace.py``), ``{}`` when the run was not traced or no device
-  operation was seen; ``trace_work`` is the useful work answered while
-  the trace ran;
+  operation was seen; a front's is every worker's trace, each chip a
+  device; ``trace_work`` is the useful work answered while the trace ran;
 * ``peaks``: the device's entry of ``bench/peaks.json``;
 * ``chips``: chips the cell runs on.
 """
@@ -55,6 +59,19 @@ def counter_delta(ctx: dict, name: str) -> float:
     c0 = ctx["stats0"].get("counters", {})
     c1 = ctx["stats1"].get("counters", {})
     return float(c1.get(name, 0.0)) - float(c0.get(name, 0.0))
+
+
+def worker_counter_deltas(ctx: dict, name: str) -> list:
+    """Growth of counter ``name`` over the window in each worker of a
+    front, in worker order; ``[]`` where the cell has no front."""
+    before = {w["index"]: w.get("counters", {})
+              for w in ctx["stats0"].get("per_worker", ())}
+    deltas = []
+    for w in sorted(ctx["stats1"].get("per_worker", ()), key=lambda w: w["index"]):
+        c0 = before.get(w["index"], {})
+        deltas.append(float(w.get("counters", {}).get(name, 0.0))
+                      - float(c0.get(name, 0.0)))
+    return deltas
 
 
 def stage_mean_ms(ctx: dict, name: str) -> Optional[float]:

@@ -31,3 +31,42 @@ def run_cell(monkeypatch, workload: str, mix_file: str, seed: int) -> dict:
     args = argparse.Namespace(workload=workload, seed=seed, seconds=2.0,
                               trace=0, rehearse=True, control=False)
     return run.run(args)
+
+
+# Faults planted in the workers of a front.  A test puts one of these in
+# the place of ``replica.worker_gateway``; each worker process imports it
+# from this module, breaks the program there and builds the gateway.
+
+def frozen_worker_gateway(*args, **kw):
+    """A worker whose pool step returns its state unchanged."""
+    import replica
+    from repro.engine.base import Engine
+
+    real = Engine._masked_stream_step
+
+    def frozen(self, params, x_t, state, mask):
+        y_t, _ = real(self, params, x_t, state, mask)
+        return y_t, state
+
+    Engine._masked_stream_step = frozen
+    return replica.worker_gateway(*args, **kw)
+
+
+def altering_worker_gateway(*args, **kw):
+    """A worker that alters one answer of its 40th pool step by 1%."""
+    import replica
+    from repro.gateway.pool import SessionPool
+
+    real = SessionPool.step
+    calls = [0]
+
+    def altered(self, inputs):
+        out = real(self, inputs)
+        calls[0] += 1
+        if calls[0] == 40:
+            sid = next(iter(out))
+            out[sid] *= 1.01
+        return out
+
+    SessionPool.step = altered
+    return replica.worker_gateway(*args, **kw)
