@@ -113,6 +113,9 @@ CASES: dict[str, tuple] = {
     # step: single sample, pipelined samples, durable response (seq+token)
     "step_req_single": (wire.OP_STEP, 0, 7, {"t": 1}, f32s(32, 6)),
     "step_req_pipelined": (wire.OP_STEP, 0, 8, {"t": 16}, f32s(16 * 32, 7)),
+    # a token stream's STEP: one int32 token id, "dtype": "i4"
+    "step_req_tokens": (wire.OP_STEP, 0, 14, {"t": 1, "dtype": "i4"},
+                        struct.pack("<i", 4097)),
     "step_resp_durable": (wire.OP_STEP, RESP, 7,
                           {"ok": True, "op": "step", "t": 1,
                            "running_error": 0.25, "seq": 41,
@@ -153,6 +156,10 @@ CASES: dict[str, tuple] = {
                              "error": "ExpiredTokenError",
                              "message": "token older than every retained snapshot"},
                             b""),
+    "error_token_out_of_range": (wire.OP_STEP, ERR, 27,
+                                 {"ok": False, "op": "step", "error": "ValueError",
+                                  "message": "token id 65536 is outside the "
+                                             "vocabulary [0, 65536)"}, b""),
     "error_unknown_op": (0x7F, ERR, 26,
                          {"ok": False, "op": "?", "error": "ValueError",
                           "message": "unknown opcode 0x7f"}, b""),

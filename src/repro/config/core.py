@@ -77,6 +77,7 @@ class ModelConfig:
     vocab_size: int = 0
     head_dim: Optional[int] = None
     norm: str = "rmsnorm"    # rmsnorm | layernorm | nonparametric_ln
+    norm_eps: float = 1e-5
     activation: str = "swiglu"  # swiglu | gelu
     rope_theta: float = 10000.0
     max_seq_len: int = 524_288

@@ -23,6 +23,7 @@ _ARCH_MODULES: dict[str, str] = {
     "rwkv6-7b": "repro.configs.rwkv6_7b",
     "whisper-large-v3": "repro.configs.whisper_large_v3",
     "jamba-v0.1-52b": "repro.configs.jamba_v0_1_52b",
+    "jamba2-3b": "repro.configs.jamba2_3b",
     "phi-3-vision-4.2b": "repro.configs.phi_3_vision_4_2b",
     # the paper's own models (Section 4.1)
     "lstm-ae-f32-d2": "repro.configs.lstm_ae_f32_d2",

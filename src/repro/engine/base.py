@@ -14,6 +14,13 @@ talk to the same four methods regardless of which schedule executes:
 Schedule choice is therefore a config knob (``EngineConfig.schedule`` or a
 plain string), which is what the paper's sequential-vs-temporal-parallel
 comparison needs and what future backends plug into.
+
+The session pool reads a model's streams through ``engine.stream_model``
+(a :class:`~repro.models.api.StreamModel`): the LSTM-AE's is the Engine's
+own masked cell step with its reconstruction MSE.  Window scoring and
+fitting run the stacked LSTM-AE the schedules execute; a model without one
+(Jamba: one token a step, answered its surprisal) serves streams only,
+through the stream model its family's ``ModelAPI`` gives, and they refuse.
 """
 from __future__ import annotations
 
@@ -25,11 +32,13 @@ from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.config.core import ModelConfig
 from repro.core.latency import PAPER_RH_M, LatencyEstimate, fpga_latency_ms
 from repro.engine.placement import Placement
 from repro.engine.schedules import Schedule, resolve_schedule
+from repro.models.api import StreamModel, build_model
 from repro.utils import Params
 
 
@@ -125,16 +134,38 @@ class Engine:
         engine_cfg: Union[str, EngineConfig] = "wavefront",
         params: Optional[Params] = None,
     ):
-        if cfg.family != "lstm_ae" or cfg.lstm_ae is None:
-            raise ValueError(
-                f"Engine executes the paper's lstm_ae family; got {cfg.family!r}"
-            )
         self.cfg = cfg
         self.engine_cfg = _as_engine_cfg(engine_cfg)
+        self.params = params
+        # Compile profiling: a jitted program (re)traces+compiles on the
+        # first call per input shape, so the first-call wall time per
+        # (program, shape) is the compile-cost proxy — that is what makes
+        # a recompile storm on the bucket ladder visible in stats().
+        self._seen_shapes: set = set()
+        self.profile: dict = {"compiles": 0, "compile_ms": 0.0,
+                              "per_program": {}}
+        self._sharded: dict[str, "object"] = {}
+        if not self.scores_windows:
+            # a model without window programs serves streams only, through
+            # the stream model its family's ModelAPI gives
+            stream = build_model(cfg).stream
+            if stream is None:
+                raise ValueError(
+                    f"Engine serves the lstm_ae family and others with a stream "
+                    f"model; {cfg.family!r} has none"
+                )
+            self.schedule = Schedule("stream", "stream", "sequential", None)
+            self.stream_model = stream()
+            return
         self.schedule: Schedule = resolve_schedule(
             self.engine_cfg.schedule, cfg, self.engine_cfg
         )
-        self.params = params
+        self.stream_model = StreamModel(
+            sample_shape=(cfg.lstm_ae.input_features,),
+            sample_dtype=np.dtype(np.float32),
+            init_state=lambda rows, max_len: self.init_stream_state(rows),
+            step=self._loss_stream_step,
+        )
         fwd = self.schedule.forward
 
         # Whole-request programs (transpose + forward + reduction fused),
@@ -186,7 +217,6 @@ class Engine:
         # multiple.  Prejitted schedules (pipelined) manage their own batch
         # sharding, so only the schedule-independent streaming programs get
         # sharded variants there.
-        self._sharded: dict[str, "object"] = {}
         pl = self.placement
         if pl.is_sharded and self.engine_cfg.jit:
             rows = pl.row_sharding()   # builds (or fails fast on) the mesh
@@ -204,14 +234,6 @@ class Engine:
             self._sharded["mstep"] = jax.jit(
                 mstep, in_shardings=(repl, rows, rows, rows),
                 out_shardings=(rows, rows))
-
-        # Compile profiling: a jitted program (re)traces+compiles on the
-        # first call per input shape, so the first-call wall time per
-        # (program, shape) is the compile-cost proxy — that is what makes
-        # a recompile storm on the bucket ladder visible in stats().
-        self._seen_shapes: set = set()
-        self.profile: dict = {"compiles": 0, "compile_ms": 0.0,
-                              "per_program": {}}
 
     # -- placement ---------------------------------------------------------
 
@@ -295,10 +317,24 @@ class Engine:
             raise ValueError("engine has no bound params; call bind(params)")
         return self.params
 
+    @property
+    def scores_windows(self) -> bool:
+        """Whether the model scores whole windows (SCORE, fit): the
+        schedules run the stacked LSTM-AE ``cfg.lstm_ae`` describes; a
+        model without one only streams."""
+        return self.cfg.lstm_ae is not None
+
+    def _require_windows(self) -> None:
+        if not self.scores_windows:
+            raise ValueError(
+                f"{self.cfg.name} serves streams only (STEP); window scoring "
+                f"and fitting are not defined for the {self.cfg.family} family")
+
     # -- batch surface ----------------------------------------------------
 
     def reconstruct_with(self, params: Params, batch: dict) -> jnp.ndarray:
         """batch {"series": (B, T, F)} -> reconstruction (B, T, F)."""
+        self._require_windows()
         series = batch["series"]
         sharded = self._row_program("reconstruct", series.shape[0])
         return self._run_profiled(
@@ -310,6 +346,7 @@ class Engine:
         """batch {"series": (B, T, F)} -> per-sequence reconstruction MSE (B,)
         — the anomaly score of the paper's application.  Under a sharded
         placement the batch rows are scored data-parallel over the mesh."""
+        self._require_windows()
         series = batch["series"]
         sharded = self._row_program("score", series.shape[0])
         return self._run_profiled(
@@ -323,6 +360,7 @@ class Engine:
         beyond their length (and all-padding rows) do not contaminate
         scores — the micro-batching gateway's bucketed-scoring primitive
         (which pads B to a per-device multiple under a sharded placement)."""
+        self._require_windows()
         series = batch["series"]
         lengths = jnp.asarray(batch["lengths"], jnp.int32)
         sharded = self._row_program("score_masked", series.shape[0])
@@ -369,6 +407,17 @@ class Engine:
         )
         return y_t, merged
 
+    def _loss_stream_step(self, params, x, state, mask, steps):
+        # the LSTM-AE's stream contract: the masked step, and each row's
+        # reconstruction MSE as its loss
+        del steps
+        y_t, state = self._masked_stream_step(params, x, state, mask)
+        sq = jnp.mean(
+            jnp.square(y_t.astype(jnp.float32) - x.astype(jnp.float32)),
+            axis=-1,
+        )
+        return state, sq
+
     def stream_with(
         self, params: Params, x_t: jnp.ndarray, state: Params
     ) -> tuple[jnp.ndarray, Params]:
@@ -412,6 +461,7 @@ class Engine:
         ``rh_m`` defaults to the paper's Table-1 bottleneck reuse factor for
         this architecture (1 when the arch is not a paper config).
         """
+        self._require_windows()
         if rh_m is None:
             rh_m = PAPER_RH_M.get(self.cfg.name, 1)
         return fpga_latency_ms(

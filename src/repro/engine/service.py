@@ -57,13 +57,17 @@ class AnomalyService:
         schedule: Union[str, EngineConfig] = "wavefront",
         *,
         seed: int = 0,
+        params: Optional[Params] = None,
     ):
+        """``params`` serves given weights (a model too large to initialise
+        twice on one device); by default they are initialised from ``seed``."""
         cfg = get_config(model) if isinstance(model, str) else model
         self.cfg = cfg
         self.api = build_model(cfg)
         self.engine: Engine = build_engine(cfg, schedule)
         self.seed = seed
-        self.params: Params = self.api.init(jax.random.PRNGKey(seed))
+        self.params: Params = (self.api.init(jax.random.PRNGKey(seed))
+                               if params is None else params)
         self.engine.bind(self.params)
         self.threshold: Optional[float] = None
         # open gateways whose engine is a placement re-layout of ours (see
@@ -100,6 +104,7 @@ class AnomalyService:
         """Train on benign windows drawn from ``data_cfg``; binds the fitted
         params onto the engine.  Returns the final metrics (empty when
         ``steps == 0`` — the service then scores with its init params)."""
+        self.engine._require_windows()
         if steps <= 0:
             return {}
         from repro.training import build_train_step, init_train_state

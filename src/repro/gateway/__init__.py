@@ -114,12 +114,14 @@ class AnomalyGateway:
         self.telemetry = Telemetry(clock=clock, detail=obs_detail)
         self.events = EventLog(None)
         self.tracer = Tracer(clock=clock, events=self.events)
-        self.pool = SessionPool(engine, capacity, telemetry=self.telemetry)
         self.batcher = MicroBatcher(
             engine, max_batch=max_batch, max_wait_ms=max_wait_ms,
             max_queue=max_queue, max_seq_len=max_seq_len,
             telemetry=self.telemetry, clock=clock,
         )
+        # max_seq_len bounds a stream's cache too, where the model keeps one
+        self.pool = SessionPool(engine, capacity, telemetry=self.telemetry,
+                                max_seq_len=self.batcher.max_seq_len)
 
     # -- streaming sessions (pool) ----------------------------------------
 

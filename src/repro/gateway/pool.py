@@ -2,10 +2,20 @@
 
 The paper keeps its datapath fed by batching independent work into the
 same hardware pipeline; the serving-layer analogue is a fixed block of
-``capacity`` stream slots — stacked per-layer (h, c) plus running error
-sums — stepped by ONE compiled masked program regardless of which logical
-streams are resident.  Admission/eviction only touches host-side slot
-maps and zeroes the slot's state rows, so stream churn never retraces.
+``capacity`` stream slots — the model family's slot state (its
+:class:`~repro.models.api.StreamModel`: per-layer (h, c) for the LSTM-AE;
+Mamba states, KV caches and the last hidden state for Jamba) plus running
+loss sums — stepped by ONE compiled masked program regardless of which
+logical streams are resident.  The state is an opaque tree whose leaves are
+rows-first; the program donates it, so the block is never held twice.
+Admission/eviction only touches host-side slot maps and zeroes the slot's
+state rows, so stream churn never retraces.  A slot's answer is the mean of
+its per-sample losses (reconstruction MSE, or a token's surprisal).
+
+A family whose state holds a cache (``StreamModel.cached``) gives each slot
+room for ``max_seq_len`` samples; the cache length of a slot is its step
+count, and a stream that has filled it is refused
+(:class:`SequenceFullError`), never wrapped.
 
 Under a sharded :class:`~repro.engine.placement.Placement` the slot block
 itself distributes over the data mesh axis — contiguous row blocks of
@@ -36,6 +46,7 @@ import numpy as np
 
 from repro.engine.base import Engine
 from repro.gateway.telemetry import Telemetry
+from repro.models.api import StreamModel
 
 logger = logging.getLogger(__name__)
 
@@ -47,6 +58,24 @@ class PoolFullError(RuntimeError):
 
 class UnknownStreamError(KeyError):
     """A stream id that is not resident in the pool."""
+
+
+class SequenceFullError(RuntimeError):
+    """A stream's cache holds ``max_seq_len`` samples: it takes no more."""
+
+
+def pool_step_program(model: StreamModel) -> Callable:
+    """The pool's step as one program of ``(params, x, state, mask,
+    sq_sum, steps)``: the model's masked step, and each stepped row's loss
+    added to its running sum (``_pool_step``, the name the device trace
+    reads)."""
+    def _pool_step(params, x, state, mask, sq_sum, steps):
+        state, loss = model.step(params, x, state, mask, steps)
+        sq_sum = sq_sum + jnp.where(mask, loss, 0.0)
+        steps = steps + mask.astype(jnp.int32)
+        return state, sq_sum, steps
+
+    return _pool_step
 
 
 class SessionPool:
@@ -63,12 +92,17 @@ class SessionPool:
         engine: Engine,
         capacity: int,
         telemetry: Optional[Telemetry] = None,
+        max_seq_len: int = 1024,
     ):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.engine = engine
         self.capacity = capacity
-        self.features = engine.cfg.lstm_ae.input_features
+        model = self.model = engine.stream_model
+        self.sample_shape = model.sample_shape
+        self.sample_dtype = model.sample_dtype
+        self.features = int(np.prod(model.sample_shape))
+        self.max_seq_len = max_seq_len
         self.telemetry = telemetry or Telemetry()
         # the pool always lays its block out on the ENGINE's placement —
         # the masked-step programs and the slot state must agree on one
@@ -79,9 +113,12 @@ class SessionPool:
         self._block = self.placement.pad_rows(capacity)
         self.slots_per_device = self._block // self.placement.data_shards
 
-        self._state = engine.init_stream_state(self._block)
+        self._state = model.init_state(self._block, max_seq_len)
         self._sq_sum = jnp.zeros((self._block,), jnp.float32)
         self._steps = jnp.zeros((self._block,), jnp.int32)
+        # host copy of each slot's step count (its cache length), kept
+        # only where a cache bounds it
+        self._length = np.zeros((self._block,), np.int64) if model.cached else None
         self._slot_of: dict[Hashable, int] = {}
         # per-device free stacks + active counters: admission picks the
         # least-loaded device in O(devices), pops its stack in O(1) —
@@ -97,16 +134,7 @@ class SessionPool:
             self._free_by_dev[slot // self.slots_per_device].append(slot)
         self._active_by_dev = [0] * self.placement.data_shards
 
-        def _pool_step(params, x, state, mask, sq_sum, steps):
-            # one fused program: masked cell step + masked error accumulate
-            y_t, state = engine._masked_stream_step(params, x, state, mask)
-            sq = jnp.mean(
-                jnp.square(y_t.astype(jnp.float32) - x.astype(jnp.float32)),
-                axis=-1,
-            )
-            sq_sum = sq_sum + jnp.where(mask, sq, 0.0)
-            steps = steps + mask.astype(jnp.int32)
-            return state, sq_sum, steps
+        _pool_step = pool_step_program(model)
 
         def _clear_slot(state, sq_sum, steps, slot):
             state = jax.tree.map(lambda leaf: leaf.at[slot].set(0.0), state)
@@ -133,24 +161,32 @@ class SessionPool:
                 _pool_step,
                 in_shardings=(repl, rows, rows, rows, rows, rows),
                 out_shardings=(rows, rows, rows),
+                donate_argnums=(2, 4, 5),
             )
             self._clear_slot = jax.jit(
                 _clear_slot,
                 in_shardings=(rows, rows, rows, repl),
                 out_shardings=(rows, rows, rows),
+                donate_argnums=(0, 1, 2),
             )
             self._load_slot = jax.jit(
                 _load_slot,
                 in_shardings=(rows, rows, rows, repl, repl, repl, repl),
                 out_shardings=(rows, rows, rows),
+                donate_argnums=(0, 1, 2),
             )
             self._state = jax.device_put(self._state, rows)
             self._sq_sum = jax.device_put(self._sq_sum, rows)
             self._steps = jax.device_put(self._steps, rows)
         else:
-            self._pool_step = jax.jit(_pool_step) if use_jit else _pool_step
-            self._clear_slot = jax.jit(_clear_slot) if use_jit else _clear_slot
-            self._load_slot = jax.jit(_load_slot) if use_jit else _load_slot
+            # the state block is donated: a step, a clear or a load writes
+            # the block in place instead of holding a second copy
+            self._pool_step = (jax.jit(_pool_step, donate_argnums=(2, 4, 5))
+                               if use_jit else _pool_step)
+            self._clear_slot = (jax.jit(_clear_slot, donate_argnums=(0, 1, 2))
+                                if use_jit else _clear_slot)
+            self._load_slot = (jax.jit(_load_slot, donate_argnums=(0, 1, 2))
+                               if use_jit else _load_slot)
 
     # -- membership -------------------------------------------------------
 
@@ -240,6 +276,36 @@ class SessionPool:
         self._state, self._sq_sum, self._steps = self._clear_slot(
             self._state, self._sq_sum, self._steps, slot
         )
+        if self._length is not None:
+            self._length[slot] = 0
+
+    def check_room(self, stream_id: Hashable) -> None:
+        """Raise :class:`SequenceFullError` (counted as ``pool.kv_full``)
+        when the stream's cache holds ``max_seq_len`` samples."""
+        slot = self._require(stream_id)
+        if self._length is not None and self._length[slot] >= self.max_seq_len:
+            self.telemetry.count("pool.kv_full")
+            raise SequenceFullError(
+                f"stream {stream_id!r} holds max_seq_len={self.max_seq_len} "
+                f"samples; evict it (or open the gateway with a larger "
+                f"max_seq_len) to go on")
+
+    def check_samples(self, samples) -> np.ndarray:
+        """``samples`` as an array of the model's sample dtype, ``(k,) +
+        sample_shape``; ValueError on a shape, dtype or value the model
+        cannot take."""
+        arr = np.asarray(samples)
+        if arr.dtype != self.sample_dtype:
+            raise ValueError(
+                f"samples must be {self.sample_dtype.name} for "
+                f"{self.engine.cfg.name}, got {arr.dtype.name}")
+        if arr.ndim < 1 or arr.shape[1:] != self.sample_shape:
+            raise ValueError(
+                f"expected samples of shape (k,) + {self.sample_shape}, "
+                f"got {arr.shape}")
+        if self.model.check is not None:
+            self.model.check(arr)
+        return arr
 
     # -- stepping ---------------------------------------------------------
 
@@ -256,17 +322,22 @@ class SessionPool:
         with tel.span("pool.step", "pool_step_ms"):
             with tel.span("pool.assemble", "pool_assemble_ms"):
                 slots = [self._require(sid) for sid in inputs]
-                x = np.zeros((self._block, self.features), np.float32)
+                x = np.zeros((self._block,) + self.sample_shape, self.sample_dtype)
                 mask = np.zeros((self._block,), bool)
                 for sid, slot in zip(inputs, slots):
-                    sample = np.asarray(inputs[sid], np.float32)
-                    if sample.shape != (self.features,):
+                    sample = np.asarray(inputs[sid], self.sample_dtype)
+                    if sample.shape != self.sample_shape:
                         raise ValueError(
                             f"stream {sid!r}: expected sample shape "
-                            f"({self.features},), got {sample.shape}"
+                            f"{self.sample_shape}, got {sample.shape}"
                         )
                     x[slot] = sample
                     mask[slot] = True
+                if self.model.check is not None:
+                    self.model.check(x[mask])
+                if self._length is not None:
+                    for sid in inputs:
+                        self.check_room(sid)
             with tel.span("pool.launch", "pool_launch_ms"):
                 # dispatch of the step and of the error read's three ops;
                 # JAX returns without waiting for their results
@@ -276,6 +347,10 @@ class SessionPool:
                 )
                 errors = self.errors()
             tel.record_pool_step(len(slots), self.capacity)
+            if self._length is not None:
+                stepped = self._length[slots] + 1
+                self._length[slots] = stepped
+                tel.record_pool_kv(int(stepped.sum()), len(slots) * self.max_seq_len)
             with tel.span("pool.readback", "pool_readback_ms"):
                 # waits for the errors and copies them to the host
                 errs = np.asarray(errors)
@@ -330,6 +405,8 @@ class SessionPool:
             self._state, self._sq_sum, self._steps, slot, row_tree,
             jnp.float32(sq_sum), jnp.int32(steps),
         )
+        if self._length is not None:
+            self._length[slot] = steps
         self.telemetry.count("pool.restored")
         return slot
 
@@ -404,7 +481,8 @@ class StepCoalescer:
 
     def submit(self, stream_id: Hashable, xs: "np.ndarray",
                on_done: Callable) -> None:
-        """Queue the ``(k, F)`` samples ``xs`` of a resident stream."""
+        """Queue the ``(k,) + sample_shape`` samples ``xs`` of a resident
+        stream."""
         self._queues.setdefault(stream_id, deque()).append(_Pending(xs, on_done))
         if not self._due:
             self._due = True
@@ -413,16 +491,23 @@ class StepCoalescer:
     def flush(self) -> None:
         """Step until no sample waits (nothing to do when none does)."""
         self._due = False
+        cached = self.pool.model.cached
         while self._queues:
             heads = {}
             for sid in list(self._queues):
-                if sid in self.pool:
-                    heads[sid] = self._queues[sid][0]
+                if sid not in self.pool:
+                    gone = UnknownStreamError(
+                        f"stream {sid!r} was evicted while its samples waited")
+                    for job in self._queues.pop(sid):
+                        job.finish(gone)
                     continue
-                gone = UnknownStreamError(
-                    f"stream {sid!r} was evicted while its samples waited")
-                for job in self._queues.pop(sid):
-                    job.finish(gone)
+                if cached:
+                    try:
+                        self.pool.check_room(sid)
+                    except SequenceFullError as full:
+                        self._pop(sid).finish(full)
+                        continue
+                heads[sid] = self._queues[sid][0]
             if not heads:
                 return
             try:

@@ -155,7 +155,9 @@ class MicroBatcher:
         if max_seq_len < 1:
             raise ValueError(f"max_seq_len must be >= 1, got {max_seq_len}")
         self.engine = engine
-        self.features = engine.cfg.lstm_ae.input_features
+        # None where the model scores no windows (submit refuses)
+        self.features = (engine.cfg.lstm_ae.input_features
+                         if engine.scores_windows else None)
         self.max_batch = max_batch
         self.max_wait_ms = max_wait_ms
         self.max_queue = max_queue
@@ -219,6 +221,7 @@ class MicroBatcher:
         set of compiled shapes — bounded).  A bucket reaching ``max_batch``
         flushes immediately.
         """
+        self.engine._require_windows()
         arr = np.asarray(series, np.float32)
         if arr.ndim != 2 or arr.shape[1] != self.features:
             raise ValueError(

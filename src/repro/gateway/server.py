@@ -711,11 +711,13 @@ class _Connection:
     def _frame_step(self, meta: dict, data, rid: int) -> None:
         """A STEP frame: ``t`` consecutive samples for this connection's
         session in one frame (amortizes the round-trip; the response
-        returns every intermediate running error).  The samples take
-        ``t`` coalesced pool steps.  Durable sessions get their
-        ``seq``/``token`` from the LAST sample, which is exactly what a
-        replaying client needs."""
-        feats = self.gateway.pool.features
+        returns every intermediate running error).  The samples are
+        float32 (``<f4``), or int32 token ids (``<i4``) where the meta
+        says ``"dtype": "i4"``.  They take ``t`` coalesced pool steps.
+        Durable sessions get their ``seq``/``token`` from the LAST
+        sample, which is exactly what a replaying client needs."""
+        pool = self.gateway.pool
+        feats = pool.features
         if "x" in meta and not len(data):
             # JSON-style request tunneled through a generic meta frame
             req = dict(meta)
@@ -725,14 +727,20 @@ class _Connection:
         k = meta.get("t", 1)
         if not isinstance(k, int) or k < 1:
             raise ValueError(f"step frame needs integer meta t>=1, got {k!r}")
-        count = len(data) // 4
-        if k == 1 and count != feats:
-            # same message the JSON protocol's shape check produces
-            raise ValueError(
-                f"expected sample shape ({feats},), got ({count},)"
-            )
-        with self.gateway.telemetry.span("wire.decode"):
-            xs = wire.decode_f32(data, (k, feats))
+        dtype = meta.get("dtype", "f4")
+        if dtype == "f4" and pool.sample_dtype == np.float32:
+            count = len(data) // 4
+            if k == 1 and count != feats:
+                # same message the JSON protocol's shape check produces
+                raise ValueError(
+                    f"expected sample shape ({feats},), got ({count},)"
+                )
+            with self.gateway.telemetry.span("wire.decode"):
+                xs = wire.decode_f32(data, (k, feats))
+        else:
+            with self.gateway.telemetry.span("wire.decode"):
+                xs = wire.decode_array(data, (k,) + pool.sample_shape, dtype)
+            xs = pool.check_samples(xs)
         self._open_session()
 
         def answer(errors, seq, token) -> None:
@@ -809,10 +817,12 @@ class _Connection:
                 if tid is not None else None)
         # validate the payload BEFORE admitting: a malformed first step
         # must not pin a pool slot that never serves
-        x = np.asarray(req["x"], np.float32)
-        feats = self.gateway.pool.features
-        if x.shape != (feats,):
-            raise ValueError(f"expected sample shape ({feats},), got {x.shape}")
+        pool = self.gateway.pool
+        x = np.asarray(req["x"], pool.sample_dtype)
+        if x.shape != pool.sample_shape:
+            raise ValueError(
+                f"expected sample shape {pool.sample_shape}, got {x.shape}")
+        pool.check_samples(x[None])
         self._open_session()
         if span is not None:
             span.mark("dispatch")

@@ -282,6 +282,15 @@ class Telemetry:
         self.count("pool.stream_steps", active)
         self.gauge("pool.step_fill", active / max(1, capacity))
 
+    def record_pool_kv(self, tokens: int, room: int) -> None:
+        """The KV caches one pool step's rows carry: ``tokens`` their
+        lengths summed (what the step's attention reads), ``room`` those
+        rows x ``max_seq_len`` (what it has room for).  Counted only for a
+        model with a cache; ``pool.kv_full`` counts the samples refused
+        because a stream's cache was full."""
+        self.count("pool.kv_tokens", tokens)
+        self.count("pool.kv_room", room)
+
     # -- reading ----------------------------------------------------------
 
     @property

@@ -25,7 +25,9 @@ which live in the header).  ``data`` is opcode-specific raw bytes:
   contiguous ``<f4`` (meta: ``{"n", "t", "f"}``); responses return ``n``
   float32 scores.
 * ``STEP`` requests pack ``t`` samples of ``f`` features each (meta:
-  ``{"t"}``); responses return ``t`` float32 running errors.
+  ``{"t"}``), as ``<f4``; with ``"dtype": "i4"`` in the meta the samples
+  are ``<i4`` integers instead (one token id a sample for a language
+  model's streams).  Responses return ``t`` float32 running errors.
 * every other opcode is a "generic meta frame": empty ``data``, the
   whole message in ``meta`` — which lets the server reuse the JSON-era
   ``_op_*`` handlers unchanged.
@@ -263,19 +265,33 @@ def encode_f32(arr) -> bytes:
     return np.ascontiguousarray(arr, dtype="<f4").tobytes()
 
 
+#: the data dtypes a STEP frame's ``"dtype"`` meta key may name
+DTYPES = {"f4": ("<f4", "float32"), "i4": ("<i4", "int32")}
+
+
 def decode_f32(data, shape: tuple[int, ...]):
     """bytes/memoryview -> float32 ndarray *view* of ``data`` (zero copy).
 
     Validates the element count against ``shape`` before reshaping so a
     lying meta header turns into a WireProtocolError, not a numpy crash.
     """
+    return decode_array(data, shape, "f4")
+
+
+def decode_array(data, shape: tuple[int, ...], dtype: str):
+    """bytes/memoryview -> ndarray *view* of ``data`` in wire dtype
+    ``dtype`` (a key of :data:`DTYPES`), validated as :func:`decode_f32`."""
     import numpy as np
 
+    if dtype not in DTYPES:
+        raise WireProtocolError(
+            f"unknown data dtype {dtype!r}; expected one of {sorted(DTYPES)}")
+    code, name = DTYPES[dtype]
     if len(data) % 4:
         raise WireProtocolError(
-            f"payload length {len(data)} is not a multiple of float32 size"
+            f"payload length {len(data)} is not a multiple of {name} size"
         )
-    arr = np.frombuffer(data, dtype="<f4")
+    arr = np.frombuffer(data, dtype=code)
     expected = 1
     for dim in shape:
         if dim < 0:
@@ -283,7 +299,7 @@ def decode_f32(data, shape: tuple[int, ...]):
         expected *= dim
     if arr.size != expected:
         raise WireProtocolError(
-            f"payload carries {arr.size} float32 values, shape {shape} "
+            f"payload carries {arr.size} {name} values, shape {shape} "
             f"needs {expected}"
         )
     return arr.reshape(shape)

@@ -55,13 +55,14 @@ def attention_specs(cfg: ModelConfig) -> Params:
     }
 
 
-def _project_qkv(params: Params, x_q: jnp.ndarray, x_kv: jnp.ndarray, cfg: ModelConfig):
+def _project_qkv(params: Params, x_q: jnp.ndarray, x_kv: jnp.ndarray, cfg: ModelConfig,
+                 linear=apply_linear):
     hd = cfg.resolved_head_dim()
     bq, sq, _ = x_q.shape
     bk, sk, _ = x_kv.shape
-    q = apply_linear(params["q"], x_q).reshape(bq, sq, cfg.num_heads, hd)
-    k = apply_linear(params["k"], x_kv).reshape(bk, sk, cfg.num_kv_heads, hd)
-    v = apply_linear(params["v"], x_kv).reshape(bk, sk, cfg.num_kv_heads, hd)
+    q = linear(params["q"], x_q).reshape(bq, sq, cfg.num_heads, hd)
+    k = linear(params["k"], x_kv).reshape(bk, sk, cfg.num_kv_heads, hd)
+    v = linear(params["v"], x_kv).reshape(bk, sk, cfg.num_kv_heads, hd)
     q = constrain(q, ("batch", None, "tp", None))
     k = constrain(k, ("batch", None, "tp", None))
     v = constrain(v, ("batch", None, "tp", None))
@@ -170,14 +171,15 @@ def apply_attention(
     kv_chunk: int = 1024,
     q_chunks: int = 1,
     return_kv: bool = False,
+    linear=apply_linear,
 ):
     """Full-sequence attention (train / prefill). x: (B, S, D).
 
     With ``return_kv`` also returns the (post-RoPE, un-expanded) K/V for KV
-    cache population at prefill.
+    cache population at prefill.  ``linear`` as in :func:`decode_attention`.
     """
     x_kv = x if x_kv is None else x_kv
-    q, k, v = _project_qkv(params, x, x_kv, cfg)
+    q, k, v = _project_qkv(params, x, x_kv, cfg, linear)
     if use_rope:
         if positions is None:
             positions = jnp.arange(x.shape[1])
@@ -188,7 +190,7 @@ def apply_attention(
     v = _expand_kv(v, cfg.num_heads)
     out = blocked_attention(q, k, v, causal=causal, kv_chunk=kv_chunk, q_chunks=q_chunks)
     out = constrain(out, ("batch", None, "tp", None))
-    y = apply_linear(params["o"], out.reshape(x.shape[0], x.shape[1], -1))
+    y = linear(params["o"], out.reshape(x.shape[0], x.shape[1], -1))
     y = constrain(y, ("batch", "sp", None))
     if return_kv:
         return y, kv
@@ -217,8 +219,15 @@ def decode_attention(
     cfg: ModelConfig,
     use_rope: bool = True,
     update_cache: bool = True,
+    linear=apply_linear,
 ) -> tuple[jnp.ndarray, Params]:
     """One-token decode: x (B, 1, D) against cache (B, S_max, Hkv, hd).
+
+    ``cache_len`` is one length for the whole batch (a scalar) or one per
+    row ``(B,)``: each row takes its token at its own position, writes it
+    there and attends to positions ``<= its length``; a row whose length is
+    ``S_max`` or more writes nothing.  ``linear`` computes the projections
+    (``apply_linear``, or ``apply_linear_f32`` for float32 accumulation).
 
     Returns (y, new_cache).  The softmax over the cached sequence is computed
     in fp32 with explicit masking of positions >= cache_len + 1.
@@ -226,19 +235,18 @@ def decode_attention(
     b, one, _ = x.shape
     assert one == 1
     hd = cfg.resolved_head_dim()
-    q, k_new, v_new = _project_qkv(params, x, x, cfg)
+    lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,))
+    q, k_new, v_new = _project_qkv(params, x, x, cfg, linear)
     if use_rope:
-        pos = jnp.full((1,), 0, jnp.int32) + cache_len
-        q = apply_rope(q, pos, cfg.rope_theta)
-        k_new = apply_rope(k_new, pos, cfg.rope_theta)
+        q = apply_rope(q, lens[:, None], cfg.rope_theta)
+        k_new = apply_rope(k_new, lens[:, None], cfg.rope_theta)
 
     if update_cache:
-        k_cache = jax.lax.dynamic_update_slice(
-            cache["k"], k_new.astype(cache["k"].dtype), (0, cache_len, 0, 0)
-        )
-        v_cache = jax.lax.dynamic_update_slice(
-            cache["v"], v_new.astype(cache["v"].dtype), (0, cache_len, 0, 0)
-        )
+        rows = jnp.arange(b)
+        k_cache = cache["k"].at[rows, lens].set(
+            k_new[:, 0].astype(cache["k"].dtype), mode="drop")
+        v_cache = cache["v"].at[rows, lens].set(
+            v_new[:, 0].astype(cache["v"].dtype), mode="drop")
     else:
         k_cache, v_cache = cache["k"], cache["v"]
     k_cache = constrain(k_cache, ("batch", "tp", None, None))
@@ -248,9 +256,10 @@ def decode_attention(
     group = cfg.num_heads // cfg.num_kv_heads
     qg = q.reshape(b, cfg.num_kv_heads, group, hd)  # (B, Hkv, G, d) (Sq==1 folded)
     scores = jnp.einsum(
-        "bhgd,bshd->bhgs", qg, k_cache.astype(q.dtype), preferred_element_type=jnp.float32
+        "bhgd,bshd->bhgs", qg.astype(k_cache.dtype), k_cache,
+        preferred_element_type=jnp.float32,
     ) / math.sqrt(hd)
-    valid = jnp.arange(s_max)[None, :] <= cache_len  # includes the new token
+    valid = jnp.arange(s_max)[None, :] <= lens[:, None]  # includes the new token
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     m = jnp.max(scores, axis=-1, keepdims=True)
     p = jnp.exp(scores - m)
@@ -259,7 +268,7 @@ def decode_attention(
         "bhgs,bshd->bhgd", p.astype(v_cache.dtype), v_cache, preferred_element_type=jnp.float32
     )
     out = out.reshape(b, 1, cfg.num_heads * hd).astype(x.dtype)
-    y = apply_linear(params["o"], out)
+    y = linear(params["o"], out)
     y = constrain(y, ("batch", None, None))
     new_cache = {"k": k_cache, "v": v_cache} if update_cache else cache
     return y, new_cache

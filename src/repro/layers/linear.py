@@ -28,3 +28,14 @@ def apply_linear(params: Params, x: jnp.ndarray) -> jnp.ndarray:
     if "b" in params:
         y = y + params["b"].astype(x.dtype)
     return y
+
+
+def apply_linear_f32(params: Params, x: jnp.ndarray) -> jnp.ndarray:
+    """``x @ w`` with ``x`` rounded to the weight's dtype and the products
+    summed in float32, returned in float32: bf16 weights give bf16 matmul
+    inputs with float32 accumulation, float32 weights a float32 product."""
+    w = params["w"]
+    y = jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+    if "b" in params:
+        y = y + params["b"].astype(jnp.float32)
+    return y

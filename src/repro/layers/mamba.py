@@ -5,7 +5,12 @@ State h in R^{d_inner x d_state} per batch element:
     h_t = exp(dt_t * A) . h_{t-1} + dt_t * B_t * x_t     (A diagonal, <0)
     y_t = C_t . h_t + D * x_t
 
-with data-dependent (dt_t, B_t, C_t) — the "selective" part.  Sequence form
+with data-dependent (dt_t, B_t, C_t) — the "selective" part.  As in every
+Jamba, dt, B and C are RMS-normalised after ``x_proj`` (``dt_norm``,
+``b_norm``, ``c_norm``).  Projections round their input to the weight's
+dtype and accumulate in float32; the SSM recurrence and its state are
+float32 whatever the weights, the conv state keeps the dtype it is given.
+Sequence form
 uses a chunked nested scan (outer over S/chunk, inner over steps) so nothing
 of shape (S, d_inner, d_state) is ever materialised; d_inner is
 tensor-parallel (the recurrence is diagonal, so the scan stays local to each
@@ -18,7 +23,8 @@ import jax.numpy as jnp
 
 from repro.config.core import ModelConfig
 from repro.distributed.sharding import constrain
-from repro.layers.linear import apply_linear, init_linear, linear_specs
+from repro.layers.linear import apply_linear_f32, init_linear, linear_specs
+from repro.layers.norms import apply_norm, init_norm, norm_specs
 from repro.utils import Params, split_keys, truncated_normal_init
 
 
@@ -40,6 +46,9 @@ def init_mamba(key: jax.Array, cfg: ModelConfig) -> Params:
         "conv_b": jnp.zeros((d_inner,), jnp.float32),
         # x -> (dt_rank + 2*d_state): dt low-rank + B + C
         "x_proj": init_linear(k["x_bc_dt"], d_inner, dt_rank + 2 * d_state),
+        "dt_norm": init_norm("rmsnorm", dt_rank),
+        "b_norm": init_norm("rmsnorm", d_state),
+        "c_norm": init_norm("rmsnorm", d_state),
         "dt_proj": init_linear(k["dt_up"], dt_rank, d_inner, bias=True),
         "a_log": jnp.log(jnp.tile(jnp.arange(1, d_state + 1, dtype=jnp.float32), (d_inner, 1))),
         "d_skip": jnp.ones((d_inner,), jnp.float32),
@@ -54,6 +63,9 @@ def mamba_specs(cfg: ModelConfig) -> Params:
         "conv_w": (None, "tp"),
         "conv_b": ("tp",),
         "x_proj": linear_specs("tp", None),
+        "dt_norm": norm_specs("rmsnorm"),
+        "b_norm": norm_specs("rmsnorm"),
+        "c_norm": norm_specs("rmsnorm"),
         "dt_proj": linear_specs(None, "tp", bias=True),
         "a_log": ("tp", None),
         "d_skip": ("tp",),
@@ -75,16 +87,20 @@ def _causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
     y = sum(w[j].astype(x.dtype) * xp[:, j : j + x.shape[1], :] for j in range(k))
     y = y + b.astype(x.dtype)
     new_state = xp[:, -(k - 1):, :] if k > 1 else conv_state
-    return y, new_state
+    return y, new_state.astype(conv_state.dtype)
 
 
 def _ssm_inputs(params: Params, xc: jnp.ndarray, cfg: ModelConfig):
     """xc: (B, S, d_inner) post-conv activations -> dt, B_t, C_t."""
     d_inner, d_state, dt_rank = mamba_dims(cfg)
-    proj = apply_linear(params["x_proj"], xc)
+    proj = apply_linear_f32(params["x_proj"], xc)
     dt_lr, b_t, c_t = jnp.split(proj, [dt_rank, dt_rank + d_state], axis=-1)
-    dt = jax.nn.softplus(apply_linear(params["dt_proj"], dt_lr).astype(jnp.float32))
-    return dt, b_t.astype(jnp.float32), c_t.astype(jnp.float32)
+    eps = cfg.norm_eps
+    dt_lr = apply_norm(params["dt_norm"], dt_lr, "rmsnorm", eps)
+    b_t = apply_norm(params["b_norm"], b_t, "rmsnorm", eps)
+    c_t = apply_norm(params["c_norm"], c_t, "rmsnorm", eps)
+    dt = jax.nn.softplus(apply_linear_f32(params["dt_proj"], dt_lr))
+    return dt, b_t, c_t
 
 
 def ssm_scan(dt, b_t, c_t, xc, a, state, chunk: int = 256):
@@ -112,7 +128,7 @@ def ssm_scan(dt, b_t, c_t, xc, a, state, chunk: int = 256):
         da = jnp.exp(dt_t[..., None] * a[None])            # (B,di,ds)
         db = dt_t[..., None] * bt_t[:, None, :]            # (B,di,ds)
         h = da * h + db * x_t.astype(jnp.float32)[..., None]
-        y_t = jnp.einsum("bds,bs->bd", h, ct_t)
+        y_t = jnp.sum(h * ct_t[:, None, :], axis=-1)
         return h, y_t
 
     def outer(h, blk):
@@ -129,38 +145,47 @@ def ssm_step(dt, b_t, c_t, xc, a, state):
     da = jnp.exp(dt[..., None] * a[None])
     db = dt[..., None] * b_t[:, None, :]
     state = da * state + db * xc.astype(jnp.float32)[..., None]
-    y = jnp.einsum("bds,bs->bd", state, c_t)
+    y = jnp.sum(state * c_t[:, None, :], axis=-1)  # a reduction: float32 on any backend
     return y, state
+
+
+def _gated_out(params: Params, y: jnp.ndarray, xc: jnp.ndarray,
+               z: jnp.ndarray) -> jnp.ndarray:
+    y = (y + params["d_skip"].astype(jnp.float32) * xc) * jax.nn.silu(z)
+    return apply_linear_f32(params["out"], y)
 
 
 def apply_mamba(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                 state: Params | None = None, chunk: int = 256):
-    """Sequence form.  x: (B,S,D) -> (y, new_state dict)."""
+    """Sequence form.  x: (B,S,D) -> (y in x's dtype, new_state dict)."""
     bsz, s, _ = x.shape
-    d_inner, d_state, _ = mamba_dims(cfg)
     if state is None:
-        state = {
-            "ssm": jnp.zeros((bsz, d_inner, d_state), jnp.float32),
-            "conv": jnp.zeros((bsz, cfg.ssm.d_conv - 1, d_inner), x.dtype),
-        }
-    xz = apply_linear(params["in_x"], x)
-    z = apply_linear(params["in_z"], x)
+        state = init_mamba_state(cfg, bsz, x.dtype)
+    xz = apply_linear_f32(params["in_x"], x)
+    z = apply_linear_f32(params["in_z"], x)
     xz = constrain(xz, ("batch", None, "tp"))
     xc, conv_state = _causal_conv(xz, params["conv_w"], params["conv_b"], state["conv"])
     xc = jax.nn.silu(xc)
     dt, b_t, c_t = _ssm_inputs(params, xc, cfg)
-    a = -jnp.exp(params["a_log"])
+    a = -jnp.exp(params["a_log"].astype(jnp.float32))
     y, ssm_state = ssm_scan(dt, b_t, c_t, xc, a, state["ssm"], chunk=chunk)
-    y = (y.astype(x.dtype) + params["d_skip"].astype(x.dtype) * xc) * jax.nn.silu(z)
-    out = apply_linear(params["out"], y)
+    out = _gated_out(params, y, xc, z).astype(x.dtype)
     sp = "sp" if s > 1 else None
     return constrain(out, ("batch", sp, None)), {"ssm": ssm_state, "conv": conv_state}
 
 
 def apply_mamba_step(params: Params, x: jnp.ndarray, cfg: ModelConfig, state: Params):
-    """Decode step.  x: (B, D) -> (y (B,D), new_state)."""
-    y, new_state = apply_mamba(params, x[:, None, :], cfg, state)
-    return y[:, 0, :], new_state
+    """Decode step.  x: (B, D) -> (y (B,D) in x's dtype, new_state)."""
+    xz = apply_linear_f32(params["in_x"], x)
+    z = apply_linear_f32(params["in_z"], x)
+    xc, conv_state = _causal_conv(xz[:, None, :], params["conv_w"], params["conv_b"],
+                                  state["conv"])
+    xc = jax.nn.silu(xc[:, 0, :])
+    dt, b_t, c_t = _ssm_inputs(params, xc, cfg)
+    a = -jnp.exp(params["a_log"].astype(jnp.float32))
+    y, ssm_state = ssm_step(dt, b_t, c_t, xc, a, state["ssm"])
+    out = _gated_out(params, y, xc, z).astype(x.dtype)
+    return out, {"ssm": ssm_state, "conv": conv_state}
 
 
 def init_mamba_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16) -> Params:

@@ -9,14 +9,16 @@
 - prefill(params, batch) -> (logits/scores, cache) [prefill shapes]
 - decode(params, token, cache, cache_len) -> (logits, cache) [decode shapes]
 - init_cache(batch, max_len) / cache_specs()       [decode state]
+- stream() -> StreamModel, for a streams-only family the session pool serves
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.config.core import ModelConfig, ShapeConfig
 from repro.core.lstm import init_lstm_ae, lstm_ae_specs
@@ -26,6 +28,29 @@ from repro.models import rwkv6 as rwkv6_m
 from repro.models import transformer as tf_m
 from repro.models import whisper as whisper_m
 from repro.utils import Params
+
+
+class StreamModel(NamedTuple):
+    """What the session pool needs of a model family's streams.
+
+    ``sample_shape`` / ``sample_dtype``: one sample of a stream (the LSTM-AE:
+    ``(F,)`` float32; Jamba: one int32 token, ``()``).
+    ``init_state(rows, max_len)``: the zero slot state of ``rows`` streams,
+    a tree whose every leaf is rows-first; ``max_len`` bounds a cache.
+    ``step(params, x, state, mask, steps)``: one sample for the rows
+    ``mask`` selects (``steps``: samples each row has taken) -> (new state,
+    unselected rows unchanged; per-row loss), the loss the pool averages
+    into a stream's answer.
+    ``check(samples)``: raise ValueError on samples the model cannot take
+    (None: any sample of the right shape).
+    ``cached``: a slot's state holds a cache that ``max_len`` bounds.
+    """
+    sample_shape: tuple
+    sample_dtype: np.dtype
+    init_state: Callable
+    step: Callable
+    check: Optional[Callable] = None
+    cached: bool = False
 
 
 @dataclass(frozen=True)
@@ -38,6 +63,10 @@ class ModelAPI:
     decode: Optional[Callable[..., tuple[jnp.ndarray, Params]]]
     init_cache: Optional[Callable[[int, int], Params]]
     cache_specs: Optional[Callable[[], Params]]
+    #: ``stream()`` -> the :class:`StreamModel` of a family the Engine
+    #: serves without window programs; None where it brings none (the
+    #: LSTM-AE's is the Engine's own masked step, ``engine/base.py``)
+    stream: Optional[Callable[[], StreamModel]] = None
 
 
 def build_model(cfg: ModelConfig) -> ModelAPI:
@@ -73,6 +102,15 @@ def build_model(cfg: ModelConfig) -> ModelAPI:
             decode=lambda p, t, c, n: jamba_m.decode_step(p, t, c, n, cfg),
             init_cache=lambda batch, max_len: jamba_m.init_states(cfg, batch, max_len),
             cache_specs=lambda: jamba_m.state_specs(cfg),
+            # one int32 token a sample, answered its surprisal
+            stream=lambda: StreamModel(
+                sample_shape=(), sample_dtype=np.dtype(np.int32),
+                init_state=lambda rows, max_len: jamba_m.init_stream_state(
+                    cfg, rows, max_len),
+                step=lambda p, x, s, m, n: jamba_m.stream_step(p, x, s, m, n, cfg),
+                check=lambda samples: jamba_m.check_tokens(samples, cfg),
+                cached=True,
+            ),
         )
     if cfg.family == "whisper":
         return ModelAPI(

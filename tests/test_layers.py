@@ -207,9 +207,10 @@ def test_rwkv_sequence_equals_steps():
     np.testing.assert_allclose(np.asarray(st), np.asarray(st_seq), rtol=2e-4, atol=2e-5)
 
 
-def test_mamba_sequence_equals_steps():
+@pytest.mark.parametrize("arch", ["jamba-v0.1-52b", "jamba2-3b"])
+def test_mamba_sequence_equals_steps(arch):
     from repro.layers.mamba import apply_mamba, apply_mamba_step, init_mamba, init_mamba_state
-    cfg = reduced_config("jamba-v0.1-52b")
+    cfg = reduced_config(arch)
     key = jax.random.PRNGKey(15)
     params = init_mamba(key, cfg)
     b, s = 2, 6

@@ -77,8 +77,12 @@ def test_transformer_decode_unroll_equals_scan():
                                    rtol=6e-2, atol=6e-2)
 
 
-def test_jamba_decode_consistent_with_prefill():
-    cfg = reduced_config("jamba-v0.1-52b")
+# jamba2-3b's served stream keeps its residual stream in float32 (its 14
+# layers in bf16 drift past this tolerance), so its paths are held there
+@pytest.mark.parametrize("arch,dtype", [("jamba-v0.1-52b", "bfloat16"),
+                                        ("jamba2-3b", "float32")])
+def test_jamba_decode_consistent_with_prefill(arch, dtype):
+    cfg = reduced_config(arch).with_overrides(compute_dtype=dtype)
     api = build_model(cfg)
     params = api.init(jax.random.PRNGKey(3))
     b, s = 2, 9

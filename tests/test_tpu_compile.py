@@ -101,3 +101,38 @@ def test_wavefront_score_program_compiles(one_chip):
     lengths = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
     compiled = engine._score_masked.lower(params, series, lengths).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_jamba_pool_step_fits_one_chip(one_chip):
+    """The session pool's step for AI21-Jamba2-3B at its served size (256
+    slots, KV room 4,096, bf16 matrices): it compiles, its state block is
+    donated (every state byte aliased to the output), and weights, state
+    and temporaries fit one chip's 16 GB."""
+    from repro.gateway.pool import pool_step_program
+    from repro.models import build_model
+
+    cfg = get_config("jamba2-3b")
+    model = build_model(cfg).stream()
+    rows, room = 256, 4096
+
+    def served(path, leaf):
+        name = getattr(path[-1], "key", "")
+        dtype = jnp.bfloat16 if name in ("w", "table", "conv_w") else jnp.float32
+        return jax.ShapeDtypeStruct(leaf.shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        served, jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0)))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: model.init_state(rows, room)))
+
+    def vec(dtype):
+        return jax.ShapeDtypeStruct((rows,), dtype, sharding=one_chip)
+
+    compiled = jax.jit(pool_step_program(model), donate_argnums=(2, 4, 5)).lower(
+        params, vec(jnp.int32), state, vec(jnp.bool_), vec(jnp.float32),
+        vec(jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
