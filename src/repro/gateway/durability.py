@@ -409,10 +409,13 @@ class DurableSessions:
         )
         return {"sessions": len(sessions), **out}
 
-    def maybe_snapshot(self, now: Optional[float] = None) -> bool:
+    def maybe_snapshot(self, now: Optional[float] = None,
+                       settle: Optional[Callable[[], None]] = None) -> bool:
         """Cadence tick, called from the server's background pump.  Skips
         (never blocks) while the previous write is in flight; skips
-        back-to-back empty snapshots so an idle worker stops writing."""
+        back-to-back empty snapshots so an idle worker stops writing.
+        ``settle`` runs just before a snapshot is taken: the transport's
+        pool step in flight lands, so the state and each seq agree."""
         now = self._clock() if now is None else now
         self._expire_parked(now)
         if self._last_snapshot_t is not None:
@@ -425,6 +428,8 @@ class DurableSessions:
             return False
         if self._was_empty and not self._seq and not self._parked:
             return False
+        if settle is not None:
+            settle()
         self.snapshot_now()
         return True
 

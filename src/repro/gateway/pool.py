@@ -37,7 +37,9 @@ LSTM cell, and unmasked slots carry their state unchanged.
 from __future__ import annotations
 
 import logging
+import threading
 from collections import deque
+from queue import SimpleQueue
 from typing import Callable, Hashable, Mapping, Optional
 
 import jax
@@ -62,6 +64,32 @@ class UnknownStreamError(KeyError):
 
 class SequenceFullError(RuntimeError):
     """A stream's cache holds ``max_seq_len`` samples: it takes no more."""
+
+
+#: steps whose launch and wait times :attr:`SessionPool.device_bound` averages
+TIMING_WINDOW = 8
+
+
+class StepLaunch:
+    """One pool step dispatched by :meth:`SessionPool.launch`: the streams
+    it steps and their slots, its errors on their way to the host, and its
+    timing (``launch_ms`` the dispatch, ``loop_ms`` the caller's whole
+    launch, clock readings at the launch's end and once the errors are on
+    the host)."""
+
+    __slots__ = ("sids", "slots", "errors", "launch_ms", "t_launched",
+                 "loop_ms", "host", "exc", "t_ready")
+
+    def __init__(self, sids, slots, errors, launch_ms: float, t_launched: float):
+        self.sids = sids
+        self.slots = slots
+        self.errors = errors
+        self.launch_ms = launch_ms
+        self.t_launched = t_launched
+        self.loop_ms = 0.0
+        self.host = None
+        self.exc: Optional[BaseException] = None
+        self.t_ready: Optional[float] = None
 
 
 def pool_step_program(model: StreamModel) -> Callable:
@@ -133,6 +161,9 @@ class SessionPool:
         for slot in range(capacity - 1, -1, -1):
             self._free_by_dev[slot // self.slots_per_device].append(slot)
         self._active_by_dev = [0] * self.placement.data_shards
+        # host launch and device wait of the last few steps (device_bound)
+        self._launch_ms: deque = deque(maxlen=TIMING_WINDOW)
+        self._wait_ms: deque = deque(maxlen=TIMING_WINDOW)
 
         _pool_step = pool_step_program(model)
 
@@ -315,46 +346,116 @@ class SessionPool:
         ``inputs`` maps resident stream ids to their next sample ``(F,)``;
         any subset of residents may step (the rest carry unchanged).
         Returns {stream_id: running mean error so far} for stepped streams.
+        The synchronous form of :meth:`launch` then :meth:`collect`, in one
+        ``pool.step`` span.
         """
         if not inputs:
             return {}
+        with self.telemetry.span("pool.step", "pool_step_ms"):
+            return self._collect(self._launch(inputs, chained=False))
+
+    def launch(self, inputs: Mapping[Hashable, "np.ndarray"],
+               chained: bool = False) -> "StepLaunch":
+        """Dispatch one step of the non-empty ``inputs`` without waiting for
+        it: the errors start their copy to the host, :meth:`wait` blocks
+        until they are there and :meth:`collect` hands them back.  Until
+        then the pool's state is the step's output, so a caller that reads
+        it (an export, an error) waits for the step.  ``chained`` counts
+        the step as launched ahead of the previous step's answers
+        (``pool.steps_chained``)."""
         tel = self.telemetry
-        with tel.span("pool.step", "pool_step_ms"):
-            with tel.span("pool.assemble", "pool_assemble_ms"):
-                slots = [self._require(sid) for sid in inputs]
-                x = np.zeros((self._block,) + self.sample_shape, self.sample_dtype)
-                mask = np.zeros((self._block,), bool)
-                for sid, slot in zip(inputs, slots):
-                    sample = np.asarray(inputs[sid], self.sample_dtype)
-                    if sample.shape != self.sample_shape:
-                        raise ValueError(
-                            f"stream {sid!r}: expected sample shape "
-                            f"{self.sample_shape}, got {sample.shape}"
-                        )
-                    x[slot] = sample
-                    mask[slot] = True
-                if self.model.check is not None:
-                    self.model.check(x[mask])
-                if self._length is not None:
-                    for sid in inputs:
-                        self.check_room(sid)
-            with tel.span("pool.launch", "pool_launch_ms"):
-                # dispatch of the step and of the error read's three ops;
-                # JAX returns without waiting for their results
-                self._state, self._sq_sum, self._steps = self._pool_step(
-                    self.engine._require_params(), jnp.asarray(x), self._state,
-                    jnp.asarray(mask), self._sq_sum, self._steps,
-                )
-                errors = self.errors()
-            tel.record_pool_step(len(slots), self.capacity)
+        t0 = tel.now()
+        with tel.span("pool.step"):
+            handle = self._launch(inputs, chained)
+        handle.loop_ms = (tel.now() - t0) * 1e3
+        return handle
+
+    def wait(self, handle: "StepLaunch") -> None:
+        """Block until ``handle``'s errors are on the host, as the span
+        ``pool.readback``.  Safe on any thread: it touches the handle and
+        the profiler's annotation only; a failure of the step is kept for
+        :meth:`collect` to raise."""
+        with self.telemetry.span("pool.readback"):
+            try:
+                handle.host = np.asarray(handle.errors)
+            except Exception as exc:
+                handle.exc = exc
+        handle.t_ready = self.telemetry.now()
+
+    def collect(self, handle: "StepLaunch") -> dict:
+        """{stream_id: running mean error} of a launched step, waiting for
+        it here unless :meth:`wait` has.  Its ``pool_step_ms`` sample is the
+        caller's own time for the step: the launch and this call."""
+        tel = self.telemetry
+        t0 = tel.now()
+        with tel.span("pool.step"):
+            try:
+                return self._collect(handle)
+            finally:
+                tel.observe_stage("pool_step_ms",
+                                  handle.loop_ms + (tel.now() - t0) * 1e3)
+
+    @property
+    def device_bound(self) -> bool:
+        """Whether the device is the longer pole: over the last few steps
+        the mean wait for a step's errors (from the launch's end until
+        they are on the host) exceeds the mean launch.  False until a step
+        has been timed."""
+        return sum(self._wait_ms) > sum(self._launch_ms)
+
+    def _launch(self, inputs, chained: bool) -> "StepLaunch":
+        tel = self.telemetry
+        with tel.span("pool.assemble", "pool_assemble_ms"):
+            slots = [self._require(sid) for sid in inputs]
+            x = np.zeros((self._block,) + self.sample_shape, self.sample_dtype)
+            mask = np.zeros((self._block,), bool)
+            for sid, slot in zip(inputs, slots):
+                sample = np.asarray(inputs[sid], self.sample_dtype)
+                if sample.shape != self.sample_shape:
+                    raise ValueError(
+                        f"stream {sid!r}: expected sample shape "
+                        f"{self.sample_shape}, got {sample.shape}"
+                    )
+                x[slot] = sample
+                mask[slot] = True
+            if self.model.check is not None:
+                self.model.check(x[mask])
             if self._length is not None:
-                stepped = self._length[slots] + 1
-                self._length[slots] = stepped
-                tel.record_pool_kv(int(stepped.sum()), len(slots) * self.max_seq_len)
-            with tel.span("pool.readback", "pool_readback_ms"):
+                for sid in inputs:
+                    self.check_room(sid)
+        with tel.span("pool.launch", "pool_launch_ms") as launch:
+            # dispatch of the step and of the error read's three ops, and
+            # the start of the errors' copy to the host; JAX returns
+            # without waiting for their results
+            self._state, self._sq_sum, self._steps = self._pool_step(
+                self.engine._require_params(), jnp.asarray(x), self._state,
+                jnp.asarray(mask), self._sq_sum, self._steps,
+            )
+            errors = self.errors()
+            errors.copy_to_host_async()
+        handle = StepLaunch(list(inputs), slots, errors, launch.ms, tel.now())
+        tel.record_pool_step(len(slots), self.capacity, chained)
+        if self._length is not None:
+            stepped = self._length[slots] + 1
+            self._length[slots] = stepped
+            tel.record_pool_kv(int(stepped.sum()), len(slots) * self.max_seq_len)
+        return handle
+
+    def _collect(self, handle: "StepLaunch") -> dict:
+        if handle.t_ready is None:
+            with self.telemetry.span("pool.readback", "pool_readback_ms") as wait:
                 # waits for the errors and copies them to the host
-                errs = np.asarray(errors)
-        return {sid: float(errs[slot]) for sid, slot in zip(inputs, slots)}
+                errs = np.asarray(handle.errors)
+            wait_ms = wait.ms
+        else:
+            if handle.exc is not None:
+                raise handle.exc
+            errs = handle.host
+            wait_ms = (handle.t_ready - handle.t_launched) * 1e3
+            self.telemetry.observe_stage("pool_readback_ms", wait_ms)
+        self._launch_ms.append(handle.launch_ms)
+        self._wait_ms.append(wait_ms)
+        return {sid: float(errs[slot]) for sid, slot in zip(handle.sids, handle.slots)}
 
     # -- durability export / restore --------------------------------------
     #
@@ -450,29 +551,53 @@ class StepCoalescer:
     the first submit since the last flush, hands :meth:`flush` to
     ``schedule`` (the server passes ``loop.call_soon``, so every frame read
     in the same pass of the loop is queued by the time the flush runs).  A
-    flush repeats until every FIFO is empty: the head sample of each
-    stream that has one, ONE :meth:`SessionPool.step` over all of them,
-    each running error handed back.  A step carries whatever arrived —
-    nothing waits on a timer, so a lone stream steps alone as before — and
-    rows are independent through the cell (the contract above), so each
-    error is bit-for-bit what the stream sees stepped alone.  A frame of
-    ``k`` samples takes ``k`` steps; frames of one stream step in arrival
-    order.
+    step takes the head sample of each stream that has one and hands each
+    running error back.  A step carries whatever arrived — nothing waits
+    on a timer, so a lone stream steps alone as before — and rows are
+    independent through the cell (the contract above), so each error is
+    bit-for-bit what the stream sees stepped alone.  A frame of ``k``
+    samples takes ``k`` steps; frames of one stream step in arrival order.
+
+    The flush steps in one of two ways, by what the pool observes
+    (:attr:`SessionPool.device_bound`), with the pool's first steps
+    synchronous:
+
+    * **synchronous**, while the host launch is the longer pole: steps
+      one after another until every FIFO is empty, each waited for on the
+      caller's thread;
+    * **chained**, while the wait for the device is: at most one step in
+      flight.  The flush launches it and returns; a waiter thread blocks
+      on its errors and hands its completion to ``post`` (the server
+      passes ``loop.call_soon_threadsafe``).  On completion the next step
+      is launched over every sample waiting (counted
+      ``pool.steps_chained``), and only then are the finished step's
+      frames answered, so the loop reads, decodes and answers while the
+      device computes.  Each step is launched after the previous one's
+      errors are on the host, so the donated state is never read by a
+      pending step.  Without ``post`` every flush is synchronous.
 
     ``on_done(errors, exc)`` is called once per frame with the running
     errors of its samples that were stepped: all ``k`` and ``exc=None``
     once the last is known.  An engine exception fails every frame of
     that step with it (their remaining samples are dropped, the other
-    streams step on), and a stream evicted while its samples wait is
-    skipped, its frames failed with :class:`UnknownStreamError`.
+    streams step on), and a stream evicted while its samples wait (or
+    while its step is in flight) is skipped, its frames failed with
+    :class:`UnknownStreamError`.  :meth:`settle` answers everything queued
+    before it returns.
     """
 
     def __init__(self, pool: SessionPool,
-                 schedule: Callable[[Callable[[], None]], object]):
+                 schedule: Callable[[Callable[[], None]], object],
+                 post: Optional[Callable[..., object]] = None):
         self.pool = pool
         self._schedule = schedule
+        self._post = post
         self._queues: dict[Hashable, deque] = {}
         self._due = False
+        # the chained step in flight: (its StepLaunch, its head frames)
+        self._inflight: Optional[tuple] = None
+        self._handoff: SimpleQueue = SimpleQueue()
+        self._waiter: Optional[threading.Thread] = None
 
     @property
     def pending(self) -> int:
@@ -489,25 +614,59 @@ class StepCoalescer:
             self._schedule(self.flush)
 
     def flush(self) -> None:
-        """Step until no sample waits (nothing to do when none does)."""
+        """This pass's step: nothing while a step is in flight (its
+        completion launches what waits), else one chained launch or
+        synchronous steps until no sample waits."""
         self._due = False
+        if self._inflight is not None:
+            return
+        if self._post is not None and self.pool.device_bound:
+            self._finish(self._launch(chained=False))
+        else:
+            self._step_sync()
+
+    def settle(self) -> None:
+        """Step every waiting sample before returning: the step in flight
+        is waited for and its frames answered, then the rest step
+        synchronously."""
+        if self._inflight is not None:
+            self._finish(self._land())
+        self._step_sync()
+
+    def close(self) -> None:
+        """:meth:`settle`, then stop the waiter thread (a later chained
+        step starts another)."""
+        self.settle()
+        waiter, self._waiter = self._waiter, None
+        if waiter is not None:
+            self._handoff.put(None)
+            waiter.join()
+
+    def _heads(self) -> tuple[dict, list]:
+        """The head frame of each waiting stream that can step, and the
+        frames that cannot, with their exception: every frame of a stream
+        no longer resident, the head frame of one whose cache is full."""
+        heads, failed = {}, []
         cached = self.pool.model.cached
-        while self._queues:
-            heads = {}
-            for sid in list(self._queues):
-                if sid not in self.pool:
-                    gone = UnknownStreamError(
-                        f"stream {sid!r} was evicted while its samples waited")
-                    for job in self._queues.pop(sid):
-                        job.finish(gone)
+        for sid in list(self._queues):
+            if sid not in self.pool:
+                gone = UnknownStreamError(
+                    f"stream {sid!r} was evicted while its samples waited")
+                failed += [(job, gone) for job in self._queues.pop(sid)]
+                continue
+            if cached:
+                try:
+                    self.pool.check_room(sid)
+                except SequenceFullError as full:
+                    failed.append((self._pop(sid), full))
                     continue
-                if cached:
-                    try:
-                        self.pool.check_room(sid)
-                    except SequenceFullError as full:
-                        self._pop(sid).finish(full)
-                        continue
-                heads[sid] = self._queues[sid][0]
+            heads[sid] = self._queues[sid][0]
+        return heads, failed
+
+    def _step_sync(self) -> None:
+        while self._queues:
+            heads, failed = self._heads()
+            self._finish(failed)
             if not heads:
                 return
             try:
@@ -516,14 +675,92 @@ class StepCoalescer:
             except Exception as exc:
                 # the batcher's ticket semantics: this step's frames answer
                 # the error, every other sample still steps
-                for sid in heads:
-                    self._pop(sid).finish(exc)
+                self._finish([(self._pop(sid), exc) for sid in heads])
                 continue
-            for sid, job in heads.items():
-                job.errors[job.next] = running[sid]
-                job.next += 1
-                if job.next == len(job.xs):
-                    self._pop(sid).finish()
+            self._finish(self._advance(heads, running))
+
+    def _launch(self, chained: bool) -> list:
+        """Launch one step over the head sample of every waiting stream and
+        hand it to the waiter thread; returns the frames that failed
+        instead, with their exception, to be answered by the caller."""
+        failed: list = []
+        while self._queues:
+            heads, cannot = self._heads()
+            failed += cannot
+            if not heads:
+                break
+            try:
+                handle = self.pool.launch(
+                    {sid: job.xs[job.next] for sid, job in heads.items()},
+                    chained)
+            except Exception as exc:
+                failed += [(self._pop(sid), exc) for sid in heads]
+                continue
+            self._inflight = (handle, heads)
+            if self._waiter is None:
+                self._waiter = threading.Thread(
+                    target=self._await_steps, name="pool-step-waiter",
+                    daemon=True)
+                self._waiter.start()
+            self._handoff.put(handle)
+            break
+        return failed
+
+    def _await_steps(self) -> None:
+        """The waiter thread: block until each handed step's errors are on
+        the host, then post its completion to the owner's thread."""
+        while True:
+            handle = self._handoff.get()
+            if handle is None:
+                return
+            self.pool.wait(handle)
+            try:
+                self._post(self._on_ready, handle)
+            except RuntimeError:  # the loop has closed: nobody to answer
+                logger.debug("step completion not posted", exc_info=True)
+
+    def _on_ready(self, handle: StepLaunch) -> None:
+        """A chained step's errors are on the host: launch the next step
+        over what waits if the device is still the longer pole, then
+        answer the finished step's frames (else answer them and step the
+        rest synchronously)."""
+        if self._inflight is None or self._inflight[0] is not handle:
+            return  # settle() has taken this step already
+        done = self._land()
+        if self._queues and self.pool.device_bound:
+            done += self._launch(chained=True)
+        self._finish(done)
+        if self._inflight is None:
+            self._step_sync()
+
+    def _land(self) -> list:
+        """Collect the step in flight: the frames it completes or fails,
+        with their exception, to be answered by the caller."""
+        handle, heads = self._inflight
+        self._inflight = None
+        try:
+            running = self.pool.collect(handle)
+        except Exception as exc:
+            return [(self._pop(sid), exc) for sid in heads]
+        return self._advance(heads, running)
+
+    def _advance(self, heads: dict, running: dict) -> list:
+        """Each head frame takes its sample's running error (a stream
+        evicted meanwhile is skipped); returns the frames now complete."""
+        done = []
+        for sid, job in heads.items():
+            if sid not in self.pool:
+                continue
+            job.errors[job.next] = running[sid]
+            job.next += 1
+            if job.next == len(job.xs):
+                done.append((self._pop(sid), None))
+        return done
+
+    @staticmethod
+    def _finish(frames: list) -> None:
+        for job, exc in frames:
+            job.finish(exc)
 
     def _pop(self, stream_id: Hashable) -> _Pending:
         queue = self._queues[stream_id]

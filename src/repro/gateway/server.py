@@ -107,7 +107,10 @@ what proves client fallback in tests).
 Concurrency model: everything touching the gateway (handlers + pump)
 runs on ONE event loop, preserving the gateway's single-threaded
 contract; JAX calls block the loop for one step/flush at a time, which
-is the micro-batching granularity anyway.  ``drain()`` is the graceful
+is the micro-batching granularity anyway — except a pool step the
+coalescer chains, whose wait for the device happens on its waiter
+thread, which touches only that step's errors and posts the completion
+back to the loop.  ``drain()`` is the graceful
 shutdown: stop accepting, flush the queue so every pending ticket
 answers, then evict sessions and close connections.
 
@@ -118,8 +121,11 @@ current pass of the event loop has read every frame that arrived, and
 steps the head sample of every waiting stream in ONE pool step.  A
 frame is answered when its last sample's running error is known (a
 durable frame's ``seq``/``token`` are assigned then, in step order).
+While the device is the longer pole the coalescer chains its steps: the
+next is launched as one's errors land, before its answers are written.
 ``close``, ``resume``, ``snapshot``, ``recalibrate``, ``stats``,
-connection teardown and ``drain()`` flush the coalescer before they act,
+connection teardown, cadence snapshots and ``drain()`` settle the
+coalescer before they act (the step in flight lands, the rest steps),
 so each sees every step sent before it.
 """
 from __future__ import annotations
@@ -212,8 +218,10 @@ class GatewayServer:
         # STEP samples of every connection, stepped together once per pass
         # of the event loop (handlers and flush share the loop's thread)
         self.steps = StepCoalescer(
-            gateway.pool, lambda flush: asyncio.get_running_loop().call_soon(flush)
+            gateway.pool, lambda flush: asyncio.get_running_loop().call_soon(flush),
+            post=self._post_to_loop,
         )
+        self._serving_loop: Optional[asyncio.AbstractEventLoop] = None
         # thread-mode bookkeeping (start_in_thread)
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
@@ -226,6 +234,7 @@ class GatewayServer:
         if self._server is not None:
             raise RuntimeError("server already started")
         self._draining = False
+        self._serving_loop = asyncio.get_running_loop()
         extra = {"reuse_port": True} if self.reuse_port else {}
         self._server = await asyncio.start_server(
             self._handle, self.host, self.port, limit=self.max_line_bytes,
@@ -252,7 +261,7 @@ class GatewayServer:
             await asyncio.gather(self._pump_task, return_exceptions=True)
             self._pump_task = None
         try:
-            self.steps.flush()  # every queued STEP answers
+            self.steps.close()  # every queued STEP answers
             self.gateway.flush()  # completes pending tickets -> responses go out
         except Exception:
             logger.exception("drain: final flush failed")
@@ -363,6 +372,11 @@ class GatewayServer:
             self._loop = None
             self._thread = None
 
+    def _post_to_loop(self, fn: Callable, *args) -> None:
+        """``fn(*args)`` on the serving loop's thread, from any thread (the
+        step coalescer's waiter)."""
+        self._serving_loop.call_soon_threadsafe(fn, *args)
+
     # -- the pump ----------------------------------------------------------
 
     async def _pump_loop(self) -> None:
@@ -377,8 +391,10 @@ class GatewayServer:
                     if self.gateway.durability is not None:
                         # cadence snapshots ride the pump: skip (never
                         # block) while the previous background write is
-                        # in flight
-                        self.gateway.durability.maybe_snapshot()
+                        # in flight; a step in flight lands first, so the
+                        # state and each session's seq agree
+                        self.gateway.durability.maybe_snapshot(
+                            settle=self.steps.settle)
                     if self.gateway.control is not None:
                         # control ticks ride the pump too: the controller
                         # rate-limits itself via its tick interval
@@ -843,7 +859,7 @@ class _Connection:
     def _op_close(self, req: dict, rid) -> None:
         if self.stream_id is None:
             raise ValueError("no open session on this connection (step first)")
-        self.server.steps.flush()  # the final score includes queued steps
+        self.server.steps.settle()  # the final score includes queued steps
         if self._durable is not None:
             final = self._durable.close(self.stream_id)  # forgotten: tokens die
         else:
@@ -862,7 +878,7 @@ class _Connection:
                 "this connection already carries a session; close it "
                 "before resuming another"
             )
-        self.server.steps.flush()
+        self.server.steps.settle()
         out = dur.resume(req["token"])  # token errors -> dispatch error path
         self.stream_id = out["sid"]
         payload = {"ok": True, "op": "resume", "seq": out["seq"],
@@ -877,7 +893,7 @@ class _Connection:
         if self.stream_id is None:
             return
         try:
-            self.server.steps.flush()  # queued samples step before it leaves
+            self.server.steps.settle()  # queued samples step before it leaves
             if self._durable is not None:
                 self._durable.suspend(self.stream_id)
             else:
@@ -943,7 +959,7 @@ class _Connection:
 
     def _op_recalibrate(self, req: dict, rid) -> None:
         # steps sent before the swap are answered under the old values
-        self.server.steps.flush()
+        self.server.steps.settle()
         kw = {}
         if "threshold" in req:
             kw["threshold"] = req["threshold"]
@@ -960,7 +976,7 @@ class _Connection:
         )
 
     def _op_stats(self, req: dict, rid) -> None:
-        self.server.steps.flush()
+        self.server.steps.settle()
         provider = self.server.stats_provider
         if provider is None:
             self.send({"ok": True, "op": "stats",
@@ -976,7 +992,7 @@ class _Connection:
         dur = self.gateway.durability
         if dur is None:
             raise ValueError("durability is not enabled on this server")
-        self.server.steps.flush()
+        self.server.steps.settle()
         out = dur.snapshot_now(wait=True)  # synchronous: callers use this
         self.send({"ok": True, "op": "snapshot", **out}, rid)  # as a barrier
 

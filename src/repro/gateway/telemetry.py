@@ -18,7 +18,9 @@ latency goes: ``wire_ms`` (one request through the transport),
 ``assemble_ms``, ``compute_ms`` and ``resolve_ms`` (one flush: host
 assembly, the masked score program with its readback, answering the
 tickets), and ``pool_step_ms`` with its parts ``pool_assemble_ms``,
-``pool_launch_ms`` and ``pool_readback_ms`` (one session-pool step).
+``pool_launch_ms`` and ``pool_readback_ms`` (one session-pool step: the
+caller's own time for it, so the readback, from the launch's end until
+the errors are on the host, is a part only where the caller waits).
 Stage recording is gated by ``detail`` so the overhead benchmark can
 price it.
 
@@ -273,12 +275,17 @@ class Telemetry:
         self.count("batch.wait_ms", wait_ms)
         self.observe_stage("batch_wait_ms", wait_ms)
 
-    def record_pool_step(self, active: int, capacity: int) -> None:
+    def record_pool_step(self, active: int, capacity: int,
+                         chained: bool = False) -> None:
         """One pooled streaming step advancing ``active`` of ``capacity``
         slots.  Gauges the stepped fraction as ``pool.step_fill`` (the
         per-step analogue of datapath utilization); ``pool.occupancy``
-        (resident slots / capacity) is gauged by the pool on admit/evict."""
+        (resident slots / capacity) is gauged by the pool on admit/evict.
+        ``pool.steps_chained`` counts the steps launched at the previous
+        step's completion, ahead of its answers (kept from the first step
+        on, at 0 while none is)."""
         self.count("pool.steps")
+        self.count("pool.steps_chained", int(chained))
         self.count("pool.stream_steps", active)
         self.gauge("pool.step_fill", active / max(1, capacity))
 

@@ -4,7 +4,13 @@ what its stream sees stepped alone.
 
 A test that needs frames from many connections to land in ONE pass holds
 the server's loop (a blocking callback on its thread) while the clients
-send, so the sockets are all readable when the loop resumes."""
+send, so the sockets are all readable when the loop resumes.
+
+The chained cases make the device slower than the launch (a step's errors
+reach the host ``DELAY`` seconds after they are read) or the launch
+slower than the device, and let the pool's own timings choose the mode."""
+import queue
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -22,7 +28,7 @@ from repro.engine import AnomalyService
 from repro.gateway import wire
 from repro.gateway.client import GatewayClient, GatewayClientError
 from repro.gateway.durability import enable_durability
-from repro.gateway.pool import StepCoalescer, UnknownStreamError
+from repro.gateway.pool import TIMING_WINDOW, StepCoalescer, UnknownStreamError
 from repro.gateway.server import GatewayServer
 
 CAPACITY = 12
@@ -322,3 +328,381 @@ def test_evicted_stream_is_skipped_and_its_frames_fail(svc, oracle):
     assert exc is None
     np.testing.assert_array_equal(errors, oracle(801, 2))
     assert steps.pending == 0
+
+
+# -- chained steps -----------------------------------------------------------
+
+DELAY = 0.05
+
+
+class SlowErrors:
+    """A pool step's errors that reach the host ``DELAY`` seconds after they
+    are read (a device step longer than its launch); the read raises while
+    ``fail[0]`` is positive."""
+
+    def __init__(self, errors, fail):
+        self._errors = errors
+        self._fail = fail
+
+    def copy_to_host_async(self):
+        self._errors.copy_to_host_async()
+
+    def __getitem__(self, i):
+        return self._errors[i]
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(DELAY)
+        if self._fail[0]:
+            self._fail[0] -= 1
+            raise RuntimeError("injected device failure")
+        return np.asarray(self._errors)
+
+
+def slow_device(monkeypatch, gw, fail=None) -> None:
+    """Make the pool's device slower than its launch, and step a scratch
+    stream until the pool's own timings say so."""
+    pool = gw.pool
+    real = pool.errors
+    fail = [0] if fail is None else fail
+    monkeypatch.setattr(pool, "errors", lambda: SlowErrors(real(), fail))
+    gw.admit("warm")
+    for x in _series(999, TIMING_WINDOW + 2):
+        gw.step({"warm": x})
+    gw.evict("warm")
+    assert pool.device_bound
+
+
+class Owner:
+    """The coalescer's owner thread, played by the test: flushes are
+    scheduled and completions posted here, and the test runs them."""
+
+    def __init__(self):
+        self.scheduled: list = []
+        self.posted: queue.SimpleQueue = queue.SimpleQueue()
+
+    def post(self, fn, *args):
+        self.posted.put((fn, args))
+
+    def run(self, steps: StepCoalescer) -> None:
+        """Run the scheduled flushes, then every completion, until no step
+        is in flight."""
+        while self.scheduled:
+            self.scheduled.pop(0)()
+        while steps._inflight is not None:
+            fn, args = self.posted.get(timeout=10)
+            fn(*args)
+
+
+def recorder(done: dict, sid, events: list = None):
+    def on_done(errors, exc):
+        if events is not None:
+            events.append(("answer", sid))
+        done[sid] = (np.array(errors), exc)
+    return on_done
+
+
+def chained_count(gw) -> float:
+    return gw.stats()["counters"].get("pool.steps_chained", 0)
+
+
+def wait_until(predicate, timeout: float = 10.0) -> None:
+    deadline = time.time() + timeout
+    while not predicate():
+        assert time.time() < deadline, "timed out"
+        time.sleep(0.002)
+
+
+def test_chained_step_is_launched_before_the_finished_steps_answers(
+        svc, oracle, monkeypatch):
+    gw = svc.open_gateway(capacity=CAPACITY)
+    slow_device(monkeypatch, gw)
+    owner = Owner()
+    steps = StepCoalescer(gw.pool, owner.scheduled.append, post=owner.post)
+    events, done = [], {}
+    real_launch = gw.pool.launch
+
+    def launch(inputs, chained=False):
+        events.append(("launch", sorted(inputs), chained))
+        return real_launch(inputs, chained)
+
+    monkeypatch.setattr(gw.pool, "launch", launch)
+    for sid in ("a", "b"):
+        gw.admit(sid)
+    chained0 = chained_count(gw)
+    steps.submit("a", _series(810, 2), recorder(done, "a", events))
+    steps.submit("b", _series(811, 1), recorder(done, "b", events))
+    owner.scheduled.pop()()  # the pass's flush launches and returns
+    assert events == [("launch", ["a", "b"], False)] and not done
+    fn, args = owner.posted.get(timeout=10)
+    fn(*args)
+    # a's second sample is on its way before b's answer is written
+    assert events[1:] == [("launch", ["a"], True), ("answer", "b")]
+    owner.run(steps)
+    assert events[3:] == [("answer", "a")]
+    assert chained_count(gw) - chained0 == 1
+    for sid, stream, n in (("a", 810, 2), ("b", 811, 1)):
+        errors, exc = done[sid]
+        assert exc is None
+        np.testing.assert_array_equal(errors, oracle(stream, n))
+    waiter = steps._waiter
+    steps.close()
+    assert steps._waiter is None and not waiter.is_alive()
+
+
+def test_chained_steps_through_the_server_match_solo(svc, oracle, monkeypatch):
+    """The equivalence case with the device slower than the launch, and
+    the interpreter switching threads every 10 us: steps chain, and every
+    running error is the stream's own, stepped alone."""
+    gw = svc.open_gateway(capacity=CAPACITY, max_batch=4, max_wait_ms=10.0)
+    slow_device(monkeypatch, gw)
+    server = GatewayServer(gw, port=0, pump_interval_ms=2.0)
+    host, port = server.start_in_thread()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    rounds = 2
+    lengths = [rounds * sum(sizes) for _, sizes in PLANS]
+    streams = [150 + i for i in range(len(PLANS))]
+    clients = [GatewayClient(host, port, protocol=proto) for proto, _ in PLANS]
+    got = [[] for _ in PLANS]
+    try:
+        chained0 = chained_count(gw)
+        for r in range(rounds):
+            sent = []
+            with held(server):
+                for i, (client, (_, sizes)) in enumerate(zip(clients, PLANS)):
+                    data = _series(streams[i], lengths[i])
+                    t = r * sum(sizes)
+                    for k in sizes:
+                        sent.append((i, send_step(client, data[t:t + k])))
+                        t += k
+            for i, rid in sent:
+                got[i].extend(running_errors(clients[i].collect(rid)))
+        finals = [c.end_session()["final"] for c in clients]
+        assert chained_count(gw) - chained0 >= rounds
+    finally:
+        sys.setswitchinterval(interval)
+        for c in clients:
+            c.close()
+        server.stop_in_thread()
+    for i, stream in enumerate(streams):
+        want = oracle(stream, lengths[i])
+        np.testing.assert_array_equal(np.array(got[i], np.float32), want)
+        assert finals[i] == want[-1]
+
+
+def test_chained_durable_frames_answer_in_step_order(svc, oracle, tmp_path,
+                                                    monkeypatch):
+    """One stream's frames (t = 1, 3 and 2) through chained steps: each
+    answers in order, its seq that of its last sample."""
+    gw = svc.open_gateway(capacity=CAPACITY)
+    dur = enable_durability(gw, tmp_path / "store", snapshot_interval_ms=60_000)
+    slow_device(monkeypatch, gw)
+    server = GatewayServer(gw, port=0, pump_interval_ms=2.0)
+    host, port = server.start_in_thread()
+    sizes = [1, 3, 3, 3, 3, 3, 2]  # 18 samples: the token refresh is at 16
+    try:
+        chained0 = chained_count(gw)
+        with GatewayClient(host, port) as a, GatewayClient(host, port) as b:
+            with held(server):
+                data = _series(710, 18)
+                rids, t = [], 0
+                for k in sizes:
+                    rids.append(send_step(a, data[t:t + k]))
+                    t += k
+                b_rid = send_step(b, _series(711, 3))
+            answers = [a.collect(rid) for rid in rids]
+            assert b.collect(b_rid)["seq"] == 3
+            got = [e for r in answers for e in running_errors(r)]
+            np.testing.assert_array_equal(np.array(got, np.float32), oracle(710, 18))
+            assert [r["seq"] for r in answers] == list(np.cumsum(sizes))
+            claims = [dur.store.signer.verify(r["token"]).seq for r in answers]
+            assert claims == [0, 0, 0, 0, 0, 16, 16]
+        assert chained_count(gw) - chained0 >= 15
+    finally:
+        server.stop_in_thread()
+
+
+@pytest.mark.parametrize("op", ["close", "stats", "recalibrate", "drain",
+                                "teardown"])
+def test_sync_callers_wait_for_the_step_in_flight(svc, oracle, tmp_path,
+                                                  monkeypatch, op):
+    """A t = 3 frame is in flight when the op arrives: the op waits for
+    it, and its samples are in what the op sees."""
+    gw = svc.open_gateway(capacity=CAPACITY)
+    if op == "teardown":
+        enable_durability(gw, tmp_path / "store", snapshot_interval_ms=60_000)
+    threshold = gw.threshold
+    slow_device(monkeypatch, gw)
+    server = GatewayServer(gw, port=0, pump_interval_ms=2.0)
+    host, port = server.start_in_thread()
+    data, want = _series(900, 4), oracle(900, 4)
+    client = GatewayClient(host, port)
+    try:
+        first = client.step(data[0])
+        rows0 = gw.stats()["counters"]["pool.stream_steps"]
+        rid = send_step(client, data[1:4])
+        wait_until(lambda: server.steps._inflight is not None)
+        if op == "close":
+            assert client.request("close")["final"] == want[-1]
+        elif op == "stats":
+            rows = client.stats()["counters"]["pool.stream_steps"]
+            assert rows - rows0 == 3
+        elif op == "recalibrate":
+            client.recalibrate(0.0)  # the frame answers under the old value
+        elif op == "drain":
+            server.stop_in_thread()
+        if op == "teardown":
+            client.close()  # parked with every sample it sent
+            wait_until(lambda: gw.pool.active == 0)
+            with GatewayClient(host, port) as again:
+                out = again.resume(first["token"], replay=[])
+            assert out["seq"] == 4 and out["running_error"] == want[-1]
+        else:
+            answer = client.collect(rid)
+            np.testing.assert_array_equal(
+                np.array(running_errors(answer), np.float32), want[1:4])
+            assert ("alert" in answer) is (threshold is not None)
+    finally:
+        client.close()
+        server.stop_in_thread()
+        gw.recalibrate(threshold=threshold)
+
+
+@pytest.mark.parametrize("where", ["launch", "readback"])
+def test_engine_failure_in_a_chained_step_answers_its_frames(
+        svc, oracle, monkeypatch, where):
+    """A chained launch that raises fails the frame it carried (its stepped
+    samples answered with the error); a step whose errors fail to come
+    back fails every frame in it.  Either way the streams step on."""
+    gw = svc.open_gateway(capacity=CAPACITY)
+    fail_read, fail_launch = [0], [0]
+    slow_device(monkeypatch, gw, fail_read)
+    real = gw.pool._pool_step
+
+    def broken(*args):
+        if fail_launch[0]:
+            fail_launch[0] -= 1
+            raise RuntimeError("injected device failure")
+        return real(*args)
+
+    monkeypatch.setattr(gw.pool, "_pool_step", broken)
+    owner = Owner()
+    steps = StepCoalescer(gw.pool, owner.scheduled.append, post=owner.post)
+    done = {}
+    for sid in ("a", "b"):
+        gw.admit(sid)
+    a, b = _series(820, 3), _series(821, 2)
+    steps.submit("a", a[:2], recorder(done, "a"))
+    steps.submit("b", b[:1], recorder(done, "b"))
+    if where == "readback":
+        fail_read[0] = 1
+    owner.scheduled.pop()()
+    fail_launch[0] = where == "launch"  # a's second sample, chained
+    owner.run(steps)
+    errors, exc = done["a"]
+    assert "injected device failure" in str(exc)
+    if where == "launch":
+        np.testing.assert_array_equal(errors, oracle(820, 1))
+        np.testing.assert_array_equal(done["b"][0], oracle(821, 1))
+        assert done["b"][1] is None
+        a_next = 1  # a's second sample was dropped
+    else:
+        assert len(errors) == 0 and "injected" in str(done["b"][1])
+        a_next = 1  # the device stepped a's first sample
+    done.clear()
+    steps.submit("a", a[a_next:], recorder(done, "a"))
+    steps.submit("b", b[1:], recorder(done, "b"))
+    owner.run(steps)
+    np.testing.assert_array_equal(done["a"][0], oracle(820, 3)[a_next:])
+    np.testing.assert_array_equal(done["b"][0], oracle(821, 2)[1:])
+    assert done["a"][1] is None and done["b"][1] is None
+    steps.close()
+
+
+def test_stream_evicted_while_its_step_is_in_flight_is_skipped(
+        svc, oracle, monkeypatch):
+    gw = svc.open_gateway(capacity=CAPACITY)
+    slow_device(monkeypatch, gw)
+    owner = Owner()
+    steps = StepCoalescer(gw.pool, owner.scheduled.append, post=owner.post)
+    done = {}
+    for sid in ("a", "b"):
+        gw.admit(sid)
+    steps.submit("a", _series(830, 2), recorder(done, "a"))
+    steps.submit("b", _series(831, 2), recorder(done, "b"))
+    owner.scheduled.pop()()
+    assert steps._inflight is not None
+    gw.evict("a")
+    owner.run(steps)
+    errors, exc = done["a"]
+    assert isinstance(exc, UnknownStreamError) and len(errors) == 0
+    errors, exc = done["b"]
+    assert exc is None
+    np.testing.assert_array_equal(errors, oracle(831, 2))
+    assert steps.pending == 0
+    steps.close()
+
+
+def test_short_wait_keeps_the_synchronous_path(svc, oracle, monkeypatch):
+    """A launch slower than the device: the flush steps synchronously, no
+    step is chained and no waiter thread starts."""
+    gw = svc.open_gateway(capacity=CAPACITY)
+    real = gw.pool._pool_step
+
+    def slow_launch(*args):
+        time.sleep(DELAY)
+        return real(*args)
+
+    monkeypatch.setattr(gw.pool, "_pool_step", slow_launch)
+    gw.admit("warm")
+    for x in _series(999, TIMING_WINDOW + 2):
+        gw.step({"warm": x})
+    gw.evict("warm")
+    assert not gw.pool.device_bound
+    owner = Owner()
+    steps = StepCoalescer(gw.pool, owner.scheduled.append, post=owner.post)
+    done = {}
+    for sid in ("a", "b"):
+        gw.admit(sid)
+    chained0 = chained_count(gw)
+    steps.submit("a", _series(840, 3), recorder(done, "a"))
+    steps.submit("b", _series(841, 1), recorder(done, "b"))
+    owner.scheduled.pop()()
+    assert steps._inflight is None and steps._waiter is None
+    assert owner.posted.empty() and steps.pending == 0
+    assert chained_count(gw) == chained0
+    np.testing.assert_array_equal(done["a"][0], oracle(840, 3))
+    np.testing.assert_array_equal(done["b"][0], oracle(841, 1))
+
+
+def test_cadence_snapshots_land_the_step_in_flight(svc, oracle, tmp_path,
+                                                   monkeypatch):
+    """A snapshot the pump takes while steps chain is taken with no step in
+    flight, so the state it stores and each session's seq agree."""
+    gw = svc.open_gateway(capacity=CAPACITY)
+    dur = enable_durability(gw, tmp_path / "store", snapshot_interval_ms=150)
+    slow_device(monkeypatch, gw)
+    seen = []
+    real = dur.snapshot_now
+
+    def snapshot_now(**kw):
+        seen.append(server.steps._inflight is None)
+        return real(**kw)
+
+    monkeypatch.setattr(dur, "snapshot_now", snapshot_now)
+    server = GatewayServer(gw, port=0, pump_interval_ms=2.0)
+    host, port = server.start_in_thread()
+    data = _series(850, 12)
+    try:
+        chained0 = chained_count(gw)
+        with GatewayClient(host, port) as client:
+            got = [e for t in range(0, 12, 3) for e in client.step_many(data[t:t + 3])]
+            sid = server.steps.pool.resident[0]
+            wait_until(lambda: len(seen) >= 2)
+            rec = dur.store.lookup(sid)
+            assert rec.steps == rec.seq
+        assert seen and all(seen)
+        assert chained_count(gw) > chained0
+    finally:
+        server.stop_in_thread()
+    np.testing.assert_array_equal(np.array(got, np.float32), oracle(850, 12))
