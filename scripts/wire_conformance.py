@@ -15,7 +15,7 @@ multi-window frame, max-size payload, each typed error).  This script:
   with no committed file.
 
 Changing the wire format on purpose follows the same committed-baseline
-workflow as ``benchmarks/check.py`` and ``analysis/baseline.json``:
+workflow as ``analysis/baseline.json``:
 
     PYTHONPATH=src python scripts/wire_conformance.py \
         --update --reason "bp1: added <field> because <why>"

@@ -1,11 +1,11 @@
 """CLI for the static-analysis gate: ``python -m repro.analysis``.
 
-Mirrors ``benchmarks/check.py``'s conventions — exit 0 when the tree is
-clean against the committed baseline, exit 1 on any non-baselined
-finding, and an ``--update-baseline`` flag that admits the current
-finding set instead of comparing (commit the result with reasons; the
-loader rejects entries whose reason is missing, and fresh entries carry
-an explicit "unreviewed" placeholder so nothing is suppressed silently).
+Exit 0 when the tree is clean against the committed baseline, exit 1
+on any non-baselined finding, and an ``--update-baseline`` flag that
+admits the current finding set instead of comparing (commit the result
+with reasons; the loader rejects entries whose reason is missing, and
+fresh entries carry an explicit "unreviewed" placeholder so nothing is
+suppressed silently).
 
 Usage::
 

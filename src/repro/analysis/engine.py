@@ -10,7 +10,7 @@ reads ships dead bytes forever.  This module is the mechanical checker
 for those classes (the PR 1 ``core/temporal.py`` shard_map miscompile
 and the PR 3 ticket depth-leak were both statically visible).
 
-Structure mirrors ``benchmarks/check.py``'s committed-baseline pattern:
+Structure (a committed baseline of reviewed findings):
 
 * :class:`Finding` — one diagnostic with a stable *fingerprint*
   (rule id + path + normalized source line + occurrence index, hashed)

@@ -1,5 +1,5 @@
 """Seeded arrival traces + a virtual-clock serving simulator for the
-control-plane benchmark (``gateway_adaptive`` in ``benchmarks/run.py``).
+control plane's adaptive-vs-static claims (``tests/test_control.py``).
 
 The adaptive-vs-static comparison needs load shapes a wall-clock
 micro-benchmark can't reproduce deterministically (bursts, diurnal
@@ -24,9 +24,8 @@ halves the same way the control plane itself does:
   autoscaler tick on windowed sensors, and scale-down retires a worker
   only after its in-flight flush completes (the zero-drop drain,
   modeled).  No wall-clock time is read anywhere, so results are
-  bit-identical across runs and machines — the committed
-  ``BENCH_adaptive.json`` baseline gates real behaviour changes, not
-  scheduler noise.
+  bit-identical across runs and machines — the tests assert behaviour,
+  not scheduler noise.
 """
 from __future__ import annotations
 

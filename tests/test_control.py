@@ -9,6 +9,7 @@ import json
 import numpy as np
 import pytest
 
+import control_traces
 from conftest import (
     GATEWAY_ARCH as ARCH,
     gateway_series as _series,
@@ -421,3 +422,59 @@ def test_control_loop_batching_actuates_through_front(tmp_path):
     assert front.batching_calls[0]["max_wait_ms"] == pytest.approx(2.0)
     assert loop.describe()["knobs"]["max_wait_ms"] == pytest.approx(2.0)
     loop.stop()                     # never started: stop is a clean no-op
+
+
+# -- adaptive vs static on seeded traces (virtual-clock simulator) ----------
+# Capacity is scaled (one worker = 400 req/s at full fill) so a 60 s trace
+# holds ~5e4 events; the controllers' whole world is the slo/floor ratio
+# and utilization, both kept (service = 1.2x floor, SLO = 5x floor — the
+# shape ``serving_floor_ms`` feeds the live plane).
+
+_LANES, _UNIT = 16, 400.0
+_SERVICE = _LANES * 1e3 / _UNIT      # ms per flush (scaled time)
+_FLOOR = _SERVICE / 1.2              # feedforward floor the plane sees
+_SLO = 5.0 * _FLOOR
+_SIM = dict(lanes=_LANES, service_ms=_SERVICE, slo_ms=_SLO, max_queue=64)
+
+
+def test_adaptive_meets_slo_and_beats_best_static_on_bursty_trace():
+    """Batching + autoscale 2:5 meets the p95 SLO on the bursty trace
+    at >=1.2x the goodput of the best static arm, each static arm on
+    the 2-worker fleet provisioned for the mean load."""
+    bursty = control_traces.make_trace("bursty", unit_rps=_UNIT, seed=0,
+                                       duration_s=60.0)
+    best = max(
+        control_traces.simulate(bursty, workers=2, max_batch=mb,
+                                max_wait_ms=wait, **_SIM)["goodput_rps"]
+        for mb, wait in ((16, 0.25 * _FLOOR),      # tight
+                         (4, 0.25 * _FLOOR),       # eager
+                         (16, 3.0 * _FLOOR)))      # patient
+    a = control_traces.simulate(
+        bursty, workers=2, max_batch=16, max_wait_ms=0.25 * _FLOOR,
+        batching=BatchingController(slo_p95_ms=_SLO, floor_ms=_FLOOR,
+                                    lanes=_LANES, min_wait_ms=0.05 * _FLOOR,
+                                    patience=1, cooldown_ticks=1),
+        autoscaler=Autoscaler(min_workers=2, max_workers=5,
+                              worker_rps=0.8 * _UNIT, patience=1,
+                              cooldown_ticks=1),
+        tick_s=0.5, spawn_delay_s=1.0, **_SIM)
+    assert a["p95_ms"] <= _SLO
+    assert a["goodput_rps"] >= 1.2 * best
+
+
+def test_priority_flood_sheds_bottom_class_and_tenant_bucket_rate_limits():
+    """Under a priority-2 tenant flood class 0 sheds nothing while class
+    2 takes the shedding; a per-tenant token bucket moves the shedding to
+    ``rate_limited`` without shedding class 0."""
+    adv = control_traces.make_trace("adversarial", unit_rps=_UNIT, seed=1,
+                                    duration_s=30.0)
+    p = control_traces.simulate(adv, workers=2, max_batch=16,
+                                max_wait_ms=0.25 * _FLOOR, classes=3, **_SIM)
+    assert p["shed_by_class"]["0"] == 0
+    assert p["shed_by_class"]["2"] > 0
+    t = control_traces.simulate(adv, workers=2, max_batch=16,
+                                max_wait_ms=0.25 * _FLOOR, classes=3,
+                                tenant_rate=0.5 * _UNIT, **_SIM)
+    assert t["rate_limited"] > 0
+    assert t["shed_by_class"]["0"] == 0
+    assert t["shed_by_class"]["2"] < p["shed_by_class"]["2"]
